@@ -44,6 +44,11 @@ the perf trajectory is tracked across PRs):
      vs a single request, and greedy fork-0 asserted bit-identical to
      the unforked oracle.
 
+Every row and every section of the JSON names the device it ran on
+(platform, device kind, device count).  The sharded and replica sections
+run in CPU-pinned child processes and are labelled ``cpu``: this process
+may hold the chip, and their numbers are never chip numbers.
+
 Run as ``__main__`` the script also gates on ``BENCH_baseline.json``
 (committed): a >15% regression of ``seed_vs_paged.speedup`` or
 ``speculative.speedup`` fails CI, as do a pallas-vs-xla output mismatch,
@@ -78,6 +83,23 @@ PROMPT = 16
 GEN = 32
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 JSON_PATH = ROOT / "BENCH_serve.json"
+# child sections pin the CPU: a parent that touched JAX may hold the chip
+CPU_CHILD_ENV = {"JAX_PLATFORMS": "cpu", "PYTHONPATH": str(ROOT / "src")}
+
+
+def _device() -> dict:
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
+
+
+def _tagged(rows, device):
+    """Suffix every CSV row with the device it was measured on (``device``
+    may be a callable, read as each row comes: a child section's device is
+    known once the child has reported)."""
+    for row in rows:
+        d = device() if callable(device) else device
+        yield f"{row} [{d['platform']} {d['device_kind']} x{d['device_count']}]"
 BASELINE_PATH = ROOT / "BENCH_baseline.json"
 REGRESSION_TOLERANCE = 0.15  # CI fails if speedup drops >15% vs baseline
 
@@ -573,13 +595,13 @@ def _sharded_child():
     out["overlap_ratio"] = out["mp2"]["tok_per_s"] / out["mp1"]["tok_per_s"]
     out["unified_overlap_speedup"] = (out["mp2_overlap"]["tok_per_s"]
                                       / out["mp2_overlap_off"]["tok_per_s"])
+    out["device"] = _device()
     print(json.dumps(out))
 
 
 def _bench_sharded(results):
-    env = {**os.environ,
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-           "PYTHONPATH": str(ROOT / "src")}
+    env = {**os.environ, **CPU_CHILD_ENV,
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=2"}
     r = subprocess.run([sys.executable, __file__, "--sharded-child"],
                        capture_output=True, text=True, env=env, timeout=560)
     if r.returncode != 0:
@@ -691,12 +713,12 @@ def _replicas_child():
            "replicas2_rr": run(2, "rr")}
     out["scaling_ratio"] = (out["replicas2_prefix"]["tok_per_s"]
                             / out["replicas1"]["tok_per_s"])
+    out["device"] = _device()  # the workers' env pins the same CPU backend
     print(json.dumps(out))
 
 
 def _bench_replicas(results):
-    env = {**os.environ, "JAX_PLATFORMS": "cpu",
-           "PYTHONPATH": str(ROOT / "src")}
+    env = {**os.environ, **CPU_CHILD_ENV}
     r = subprocess.run([sys.executable, __file__, "--replicas-child"],
                        capture_output=True, text=True, env=env, timeout=560)
     if r.returncode != 0:
@@ -1046,16 +1068,21 @@ def bench(results: dict | None = None):
     if results is None:
         results = {}
     results["arch"] = f"{ARCH} (reduced)"
-    yield from _bench_seed_vs_paged(cfg, model, params, results)
-    yield from _bench_equal_budget(cfg, model, params, results)
-    yield from _bench_quantized_budget(cfg, model, params, results)
-    yield from _bench_prefix_hits(cfg, model, params, results)
-    yield from _bench_mixed_load(cfg, model, params, results)
-    yield from _bench_speculative(cfg, model, params, results)
-    yield from _bench_sharded(results)
-    yield from _bench_kernels(cfg, model, params, results)
-    yield from _bench_replicas(results)
-    yield from _bench_fork_sampling(cfg, model, params, results)
+    here = results["device"] = _device()
+    # a crashed child reports nothing; its env pinned it to the CPU
+    cpu_child = {"platform": "cpu", "device_kind": "cpu", "device_count": 1}
+    for section in (_bench_seed_vs_paged, _bench_equal_budget,
+                    _bench_quantized_budget, _bench_prefix_hits,
+                    _bench_mixed_load, _bench_speculative):
+        yield from _tagged(section(cfg, model, params, results), here)
+    yield from _tagged(_bench_sharded(results),
+                       lambda: results["sharded"].get("device", cpu_child))
+    yield from _tagged(_bench_kernels(cfg, model, params, results), here)
+    yield from _tagged(
+        _bench_replicas(results),
+        lambda: results["replica_scaling"].get("device", cpu_child))
+    yield from _tagged(_bench_fork_sampling(cfg, model, params, results),
+                       here)
     JSON_PATH.write_text(json.dumps(results, indent=2) + "\n")
     yield f"serve_bench_json,,{JSON_PATH.name} written"
 

@@ -1,17 +1,10 @@
-"""JAX version compatibility shims.
+"""Two JAX defaults this repo overrides, in one place.
 
-The repo targets the current JAX API surface but must run on older
-installs too (the CI container pins an older jax).  Every API that drifted
-between versions is wrapped here, so call sites never branch on
-``jax.__version__``:
-
-  * ``make_mesh``          — ``axis_types=`` keyword only exists on newer jax;
-  * ``make_abstract_mesh`` — ``AbstractMesh`` changed from a
-    ``((name, size), ...)`` tuple to ``(shape, axis_names)`` positional args;
-  * ``shard_map``          — moved from ``jax.experimental.shard_map`` (with
-    ``check_rep=``) to ``jax.shard_map`` (with ``check_vma=``);
-  * ``cost_analysis_dict`` — ``Compiled.cost_analysis()`` has returned a
-    dict, a list of dicts (one per partition), or None depending on version.
+  * ``make_mesh`` — ``jax.make_mesh`` defaults to Explicit axis types; the
+    sharding rules here are written for Auto (GSPMD propagation).
+  * ``shard_map`` — ``jax.shard_map`` checks varying-manual-axes by
+    default; our wrappers emit io_callbacks the checker cannot reason
+    about, so it is off unless asked for.
 """
 from __future__ import annotations
 
@@ -19,52 +12,14 @@ import jax
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types when the install supports them."""
-    axis_shapes = tuple(axis_shapes)
+    """``jax.make_mesh`` with Auto axis types."""
     axis_names = tuple(axis_names)
-    if hasattr(jax.sharding, "AxisType"):
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names, devices=devices,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-            )
-        except TypeError:
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-def make_abstract_mesh(axis_shapes, axis_names):
-    """Device-free mesh for pure PartitionSpec logic (no backend touched)."""
-    axis_shapes = tuple(axis_shapes)
-    axis_names = tuple(axis_names)
-    try:
-        return jax.sharding.AbstractMesh(axis_shapes, axis_names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    return jax.make_mesh(
+        tuple(axis_shapes), axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Per-shard mapping with the replication check disabled by default
-    (our wrappers emit io_callbacks the checker cannot reason about)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to a flat dict (empty when the
-    backend reports nothing)."""
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``jax.shard_map`` with the varying-manual-axes check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

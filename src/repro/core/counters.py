@@ -37,9 +37,7 @@ class StepCounters:
 
     @classmethod
     def from_compiled(cls, compiled, coll_bytes: int = 0):
-        from repro.compat import cost_analysis_dict
-
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis() or {}
         return cls(
             flops_per_step=int(ca.get("flops", 0)),
             bytes_per_step=int(ca.get("bytes accessed", 0)),

@@ -2,8 +2,11 @@
 
 Every attention call site (dense prefill, paged decode, ragged span —
 spec verify rides the span variant) asks :func:`resolve` which backend
-to run.  The answer is a :class:`KernelDecision`; an unsupported shape or
-platform degrades to the XLA path with a reason string, NEVER an error.
+to run.  The answer is a :class:`KernelDecision`.  Off TPU an unsupported
+shape or platform degrades to the XLA path with a reason string.  On TPU
+in ``auto`` mode it is a :class:`KernelFallbackError` naming the reason: a
+chip run must not serve without its kernels unnoticed (``kernel_mode=xla``
+asks for the XLA path on purpose).
 
 Modes (``cfg.kernel_mode``, overridable via ``REPRO_KERNEL_MODE``):
 
@@ -45,6 +48,10 @@ _SUPPORTED_DTYPES = ("float32", "bfloat16")
 # re-exported: the observer also receives EV_KERNEL_VARIANT from engines
 set_observer = autotune.set_observer
 notify = autotune.notify
+
+
+class KernelFallbackError(ValueError):
+    """``auto`` mode on TPU would have run an attention variant on XLA."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,14 +112,21 @@ def resolve(mode: str, variant: str, *, head_dim: int, kv_heads: int,
         raise ValueError(f"kernel variant {variant!r}: expected one of {VARIANTS}")
     if mode == "xla":
         return KernelDecision(variant, "xla", reason="mode=xla")
-    if not supported:
-        return KernelDecision(variant, "xla", reason=why or "unsupported call site")
-    if str(dtype) not in _SUPPORTED_DTYPES:
-        return KernelDecision(variant, "xla", reason=f"dtype {dtype} unsupported")
-    if head_dim % 8:
-        return KernelDecision(variant, "xla",
-                              reason=f"head_dim {head_dim} not lane-tileable")
     plat = platform or _platform()
+    if not supported:
+        unfit = why or "unsupported call site"
+    elif str(dtype) not in _SUPPORTED_DTYPES:
+        unfit = f"dtype {dtype} unsupported"
+    elif head_dim % 8:
+        unfit = f"head_dim {head_dim} not lane-tileable"
+    else:
+        unfit = ""
+    if unfit:
+        if mode == "auto" and plat == "tpu":
+            raise KernelFallbackError(
+                f"{variant} cannot run its Pallas kernel on TPU ({unfit}); "
+                f"set kernel_mode='xla' to serve it on XLA deliberately")
+        return KernelDecision(variant, "xla", reason=unfit)
     if mode == "auto" and plat != "tpu":
         # interpret-mode Pallas is emulation, not a fast path
         return KernelDecision(variant, "xla", reason=f"auto: {plat} has no Mosaic")

@@ -53,12 +53,13 @@ def flash_attention(
 
 
 def _scale_pages(cache):
-    """Quantized pools: head-major [Hkv, NB, bs] scale pages for the kernels
-    (empty kwargs for native pools — the static `quant` flag stays False)."""
+    """Quantized pools: head-major [Hkv, NB, 1, bs] scale rows for the
+    kernels (empty kwargs for native pools — the static `quant` flag stays
+    False)."""
     if "k_scale" not in cache:
         return {}
-    return {"k_scales": jnp.transpose(cache["k_scale"], (2, 0, 1)),
-            "v_scales": jnp.transpose(cache["v_scale"], (2, 0, 1))}
+    return {n + "s": jnp.transpose(cache[n], (2, 0, 1))[:, :, None, :]
+            for n in ("k_scale", "v_scale")}
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))

@@ -37,9 +37,11 @@ def _paged_decode_kernel(
     bt_ref, idx_ref, q_ref, k_ref, v_ref, *refs,
     scale: float, window: int | None, bs: int, num_w: int, quant: bool,
 ):
-    # quantized pools append per-(position, head) scale pages after v: the
-    # scales ride the same bt[b, w] DMA schedule as their block, and dequant
-    # is a [bs]-broadcast multiply inside the online-softmax inner loop
+    # quantized pools append per-(position, head) scale rows after v: the
+    # scales ride the same bt[b, w] DMA schedule as their block.  A row
+    # [1, bs] keeps the block's last two dims equal to the array's (Mosaic
+    # tiling rule), so dequant scales the scores (keys) and the softmax
+    # weights (values) along lanes instead of the [bs, d] pages
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -66,13 +68,12 @@ def _paged_decode_kernel(
         q = q_ref[0, 0].astype(jnp.float32) * scale  # [G, d]
         k = k_ref[0, 0].astype(jnp.float32)  # [bs, d]
         v = v_ref[0, 0].astype(jnp.float32)  # [bs, d]
-        if quant:
-            k = k * ks_ref[0, 0][:, None]
-            v = v * vs_ref[0, 0][:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [G, bs]
+        if quant:
+            s = s * ks_ref[0, 0]  # [1, bs] per-position key scales
         k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = k_pos <= idx
         if window is not None:
@@ -85,6 +86,8 @@ def _paged_decode_kernel(
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_new = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        if quant:
+            p = p * vs_ref[0, 0]  # value scales fold into the weights
         pv = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -106,8 +109,8 @@ def paged_decode_fwd(
 ):
     """q: [B, Hkv, G, D]; k/v_pages: [Hkv, NB, bs, D] (head-major pool);
     block_tables: [B, W] int32; index: [B] int32 (last valid position).
-    k/v_scales (quantized pools): [Hkv, NB, bs] f32 per-position scales,
-    DMA'd block-aligned with their pages and applied in-kernel."""
+    k/v_scales (quantized pools): [Hkv, NB, 1, bs] f32 per-position scale
+    rows, DMA'd block-aligned with their pages and applied in-kernel."""
     b, hkv, g, d = q.shape
     bs = k_pages.shape[2]
     num_w = block_tables.shape[1]
@@ -127,8 +130,8 @@ def paged_decode_fwd(
     ]
     operands = [q, k_pages, v_pages]
     if quant:
-        scale_spec = pl.BlockSpec((1, 1, bs),
-                                  lambda b_, h, w, bt, idx: (h, bt[b_, w], 0))
+        scale_spec = pl.BlockSpec((1, 1, 1, bs),
+                                  lambda b_, h, w, bt, idx: (h, bt[b_, w], 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scales, v_scales]
     return pl.pallas_call(
@@ -185,13 +188,12 @@ def _paged_span_kernel(
         q = q_ref[0, 0].astype(jnp.float32) * scale  # [bq, d]
         k = k_ref[0, 0].astype(jnp.float32)  # [bs, d]
         v = v_ref[0, 0].astype(jnp.float32)  # [bs, d]
-        if quant:
-            k = k * ks_ref[0, 0][:, None]
-            v = v * vs_ref[0, 0][:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bq, bs]
+        if quant:
+            s = s * ks_ref[0, 0]  # [1, bs] per-position key scales
         # folded query row r of this tile is query (iq*bq + r) // gq of the row
         q_pos = start + (
             iq * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -208,6 +210,8 @@ def _paged_span_kernel(
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_new = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        if quant:
+            p = p * vs_ref[0, 0]  # value scales fold into the weights
         pv = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -232,7 +236,8 @@ def paged_span_fwd(
     k/v_pages: [Hkv, NB, bs, D]; block_tables: [B, W];
     row_start/row_len: [B] int32.  Rows beyond row_len are garbage by
     contract (the engine discards them).  k/v_scales (quantized pools):
-    [Hkv, NB, bs] f32, fetched alongside their pages and applied in-kernel.
+    [Hkv, NB, 1, bs] f32, fetched alongside their pages and applied
+    in-kernel.
 
     ``block_q`` tiles the folded Q*G dim over its own grid axis; the
     caller (ops.py) pads Q*G to a block multiple.  None keeps one tile.
@@ -261,7 +266,7 @@ def paged_span_fwd(
     operands = [q, k_pages, v_pages]
     if quant:
         scale_spec = pl.BlockSpec(
-            (1, 1, bs), lambda b_, h, i, w, bt, st, ln: (h, bt[b_, w], 0))
+            (1, 1, 1, bs), lambda b_, h, i, w, bt, st, ln: (h, bt[b_, w], 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scales, v_scales]
     return pl.pallas_call(

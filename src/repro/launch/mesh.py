@@ -3,8 +3,8 @@
 A FUNCTION, not a module-level constant — importing this module never
 touches jax device state (the LD_PRELOAD-ordering lesson from the paper,
 section 3.1, transposed to JAX: device count locks on first backend init).
-Mesh construction itself goes through :mod:`repro.compat` so the shape/axis
-format tracks whatever the installed jax accepts.
+Mesh construction goes through :func:`repro.compat.make_mesh` (Auto axis
+types).
 """
 from __future__ import annotations
 

@@ -164,9 +164,7 @@ def analyze_compiled(compiled, *, arch: str, shape, mesh, model_flops_global: fl
     from repro.core.hlo_cost import analyze_hlo
     from repro.launch.mesh import mesh_desc
 
-    from repro.compat import cost_analysis_dict
-
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     text = compiled.as_text()
     hc = analyze_hlo(text, total_devices=mesh.size)

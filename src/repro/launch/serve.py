@@ -30,6 +30,12 @@ stream per mesh_data TASK, merged mpi2prv-style into the final ``.prv``
 (see docs/distributed_serving.md).  On CPU the requested device count is
 forced via ``xla_force_host_platform_device_count``.
 
+``--layers N`` serves the registry config at its published widths and
+dtype, cut to its first N layers (N = the registry depth serves it whole);
+without it the CLI serves ``reduced()``, the CPU test preset.  On a TPU
+every result line names the device, and ``auto`` kernel dispatch refuses
+to fall back to XLA (``repro.kernels.attention.dispatch``).
+
 ``--overlap on|off|auto`` controls communication/compute overlap for
 sharded runs: the span batch is micro-batched inside the jitted step so
 one micro-batch's TP all-reduces drain under the other's compute, and the
@@ -96,6 +102,36 @@ def _request_extras(cfg, rng, n):
     return extras
 
 
+def _serve_config(args, parser):
+    """``reduced()`` by default; with ``--layers N`` the registry config at
+    published widths cut to N layers, with the cut printed."""
+    from repro.configs import get_config, reduced
+
+    base = get_config(args.arch)
+    if not args.layers:
+        return reduced(base)
+    n = args.layers
+    if not 1 <= n <= base.num_layers:
+        parser.error(f"--layers {n}: {args.arch} has {base.num_layers}")
+    if base.block_pattern and n % len(base.block_pattern):
+        parser.error(f"--layers {n}: {args.arch} stacks blocks of "
+                     f"{len(base.block_pattern)} layers")
+    cut = (f"depth cut by {base.num_layers - n}" if n < base.num_layers
+           else "full depth")
+    print(f"[serve] {args.arch} at published widths (d_model "
+          f"{base.d_model}, {base.num_heads}/{base.num_kv_heads} heads x "
+          f"{base.head_dim}, d_ff {base.d_ff}, vocab {base.vocab_size}, "
+          f"{base.dtype}): {n} of {base.num_layers} layers ({cut})")
+    return base.replace(num_layers=n)
+
+
+def _device_label() -> str:
+    import jax
+
+    d = jax.devices()
+    return f"{d[0].platform} {d[0].device_kind} x{len(d)}"
+
+
 def _main_replicas(args) -> int:
     """Serve through the multi-replica router (docs/router.md).
 
@@ -157,7 +193,7 @@ def _main_replicas(args) -> int:
         mode = "disaggregated" if args.disaggregate else args.route
         print(f"[serve] {args.arch} replicas={args.replicas} route={mode}: "
               f"{tokens} tokens in {seconds:.2f}s = "
-              f"{tokens / seconds:.1f} tok/s aggregate (CPU smoke scale)")
+              f"{tokens / seconds:.1f} tok/s aggregate (host wall clock)")
         st = router.stats
         print(f"[serve] router: {st['route_decisions']} decisions, "
               f"{st['bounces']} bounces, "
@@ -201,9 +237,22 @@ def _main_replicas(args) -> int:
     return 0
 
 
-def main(argv=None):
+def main(argv=None) -> int:
+    run(argv)
+    return 0
+
+
+def run(argv=None) -> dict:
+    """The CLI's body.  Returns a report of the single-engine run: the
+    config, the kernel plan (variant -> backend), the engine stats and each
+    request's generated tokens in submission order (empty for --replicas,
+    which reports on stdout only)."""
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="granite-8b")
+    p.add_argument("--layers", type=int, default=0,
+                   help="serve the registry config at published widths, "
+                        "cut to its first N layers (0 = the reduced() CPU "
+                        "test preset)")
     p.add_argument("--mode", default="unified",
                    choices=["unified", "continuous", "static"])
     p.add_argument("--max-step-tokens", type=int, default=0,
@@ -329,6 +378,9 @@ def main(argv=None):
         p.error("--beam/--session need the single in-process engine "
                 "(--replicas routes sticky sessions on its own)")
     if args.replicas:
+        if args.layers:
+            p.error("--replicas serves the reduced() preset (replica "
+                    "workers build their own config)")
         if args.mode != "unified":
             p.error("--replicas serves through UnifiedServeEngine workers "
                     "(--mode unified)")
@@ -340,7 +392,8 @@ def main(argv=None):
         if args.flush_every:
             p.error("--flush-every is per-engine; replica workers stream "
                     "their own per-task segments at shutdown")
-        return _main_replicas(args)
+        _main_replicas(args)
+        return {}
     if args.disaggregate:
         p.error("--disaggregate needs --replicas >= 2")
     mesh_shape = _parse_mesh(args, p)
@@ -352,17 +405,20 @@ def main(argv=None):
 
     from repro import core as xtrace
     from repro.compat import make_mesh
-    from repro.configs import all_arch_names, get_config, reduced
+    from repro.configs import all_arch_names
     from repro.core.analysis import serve_latency_summary
+    from repro.launch.cache import use_compile_cache
     from repro.models.model import build_model
     from repro.serve.engine import ContinuousServeEngine, ServeEngine
     from repro.serve.step import UnifiedServeEngine
+    from repro.sharding.partition import make_serve_rules
 
     if args.arch not in all_arch_names():
         p.error(f"unknown --arch {args.arch!r} (choose from "
                 f"{', '.join(all_arch_names())})")
 
-    cfg = reduced(get_config(args.arch))
+    use_compile_cache()
+    cfg = _serve_config(args, p)
     if args.kernel_mode:
         cfg = cfg.replace(kernel_mode=args.kernel_mode)
     if args.kv_dtype:
@@ -370,8 +426,12 @@ def main(argv=None):
     mesh = (make_mesh(mesh_shape, ("data", "model"))
             if mesh_shape is not None else None)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    # under a mesh every parameter is created already sharded
+    shardings = (make_serve_rules(cfg, mesh).tree_shardings(model.param_axes())
+                 if mesh is not None else None)
+    params = model.init(jax.random.PRNGKey(0), shardings)
     out = pathlib.Path(args.out)
+    outputs: list[np.ndarray] = []
 
     slots = min(args.slots, args.requests)
     if args.beam:
@@ -465,12 +525,14 @@ def main(argv=None):
                 engine.close_session(f"s{i}")
         else:
             # staggered prompt lengths exercise variable-length admission
+            reqs = []
             for i in range(args.requests):
                 plen = max(1, args.prompt_len - (i % 4))
                 ex = {k: v[i] for k, v in extras.items()}
-                engine.submit(prompts[i, :plen], args.gen, extras=ex,
-                              n_samples=args.n)
-            engine.run()
+                reqs.append(engine.submit(prompts[i, :plen], args.gen,
+                                          extras=ex, n_samples=args.n))
+            done = engine.run()
+            outputs = [done[r.rid] for r in reqs]
         stats = engine.throughput_stats()
 
     mesh_note = (f" mesh={mesh_shape[0]}dx{mesh_shape[1]}m"
@@ -478,7 +540,7 @@ def main(argv=None):
     print(f"[serve] {args.arch} mode={args.mode}{mesh_note}: "
           f"{stats['tokens']} tokens in "
           f"{stats['seconds']:.2f}s = {stats['tok_per_s']:.1f} tok/s "
-          f"(host syncs: {stats.get('host_syncs', '?')}; CPU smoke scale)")
+          f"(host syncs: {stats.get('host_syncs', '?')}; {_device_label()})")
     if args.mode != "static" and engine.pool is not None:
         print(f"[serve] paged pool: {engine.num_blocks - 1} blocks x "
               f"{engine.block_size} tokens ({engine.pool.kv_dtype} storage, "
@@ -545,7 +607,9 @@ def main(argv=None):
             print(f"[serve] forks (from trace): {fk['count']} children off "
                   f"{fk['parents']} parents, peak "
                   f"{fk['peak_shared_blocks']} blocks shared")
-    return 0
+    plan = getattr(engine, "kernel_plan", {})
+    return {"cfg": cfg, "stats": stats, "outputs": outputs,
+            "plan": {v: d.backend for v, d in plan.items()}}
 
 
 if __name__ == "__main__":

@@ -19,6 +19,7 @@ import sys
 from repro import core as xtrace
 from repro.configs import all_arch_names, get_config, reduced
 from repro.configs.base import ShapeSpec, TrainConfig
+from repro.launch.cache import use_compile_cache
 from repro.train.trainer import Trainer
 
 
@@ -38,6 +39,7 @@ def main(argv=None):
                    help="use the full architecture config (TPU-scale!)")
     args = p.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if not args.full_config:
         cfg = reduced(cfg)

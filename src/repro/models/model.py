@@ -59,8 +59,10 @@ class _Base:
         self._decl = self.decl()
 
     # ---- parameters ----
-    def init(self, key):
-        return init_params(key, self._decl, self.dtype)
+    def init(self, key, shardings=None):
+        """Random parameters in the model dtype; ``shardings`` places each
+        leaf as it is made (see :func:`repro.models.params.init_params`)."""
+        return init_params(key, self._decl, self.dtype, shardings)
 
     def abstract_params(self):
         return abstract_params(self._decl, self.dtype)

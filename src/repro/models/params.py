@@ -67,8 +67,6 @@ def stack_decls(decls: PyTree, num: int, axis_name: str | None = "layers") -> Py
 
 
 def _fan_in(shape: tuple[int, ...]) -> int:
-    # For stacked params the leading "layers" dim is not a fan-in dim; decls
-    # are initialized per-layer via vmap so plain heuristics apply here.
     if len(shape) == 0:
         return 1
     if len(shape) == 1:
@@ -114,22 +112,31 @@ def _init_leaf(key, d: ParamDecl, default_dtype) -> jax.Array:
     raise ValueError(f"unknown init {d.init!r}")
 
 
-def init_params(key, decls: PyTree, default_dtype=jnp.float32) -> PyTree:
-    """Initialize real parameters from a decl tree."""
-    leaves, treedef = jax.tree.flatten(decls, is_leaf=is_decl)
+def _build_params(key, *, leaves, treedef, default_dtype):
     keys = jax.random.split(key, max(len(leaves), 1))
     out = [_init_leaf(k, d, default_dtype) for k, d in zip(keys, leaves)]
     return jax.tree.unflatten(treedef, out)
 
 
-def init_stacked_params(key, decls: PyTree, num: int, default_dtype=jnp.float32) -> PyTree:
-    """vmap per-layer init over a leading layer dimension.
+_STATIC = ("leaves", "treedef", "default_dtype")
+_build_params_jit = jax.jit(_build_params, static_argnames=_STATIC)
 
-    ``decls`` here is the *un-stacked* decl tree; the result has a leading
-    ``num`` dim on every leaf and matches ``stack_decls(decls, num)``.
+
+def init_params(key, decls: PyTree, default_dtype=jnp.float32,
+                shardings=None) -> PyTree:
+    """Initialize real parameters from a decl tree in ONE jitted program.
+
+    Each leaf's float32 draw fuses into its cast to the leaf dtype, so no
+    float32 copy of a whole stacked ``[L, ...]`` leaf is ever held.  With
+    ``shardings`` (a tree of shardings matching the params) every leaf is
+    created in place on its devices, never whole on one device first.
     """
-    keys = jax.random.split(key, num)
-    return jax.vmap(lambda k: init_params(k, decls, default_dtype))(keys)
+    leaves, treedef = jax.tree.flatten(decls, is_leaf=is_decl)
+    fn = (_build_params_jit if shardings is None else
+          jax.jit(_build_params, static_argnames=_STATIC,
+                  out_shardings=shardings))
+    return fn(key, leaves=tuple(leaves), treedef=treedef,
+              default_dtype=default_dtype)
 
 
 def abstract_params(decls: PyTree, default_dtype=jnp.bfloat16) -> PyTree:

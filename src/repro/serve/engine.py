@@ -222,13 +222,13 @@ class ContinuousServeEngine:
         self._tok = self._dev(jnp.zeros((self.num_slots,), jnp.int32))
         self._idx = self._dev(jnp.zeros((self.num_slots,), jnp.int32))
         self._active = np.zeros((self.num_slots,), bool)  # host-side mirror
-        self._active_dev = self._dev(jnp.asarray(self._active))
+        self._active_dev = self._upload(self._active)
         self._active_dirty = False
         # per-slot block tables; entry w maps positions [w*bs, (w+1)*bs).
         # NULL rows make stale frozen-slot writes land in the garbage block.
         self._tables = np.full((self.num_slots, self.blocks_per_slot),
                                NULL_BLOCK, np.int32)
-        self._tables_dev = self._dev(jnp.asarray(self._tables))
+        self._tables_dev = self._upload(self._tables)
         self._tables_dirty = False
         self._slot_blocks: list[list[int]] = [[] for _ in range(self.num_slots)]
         # prefill-time start position per slot (request input_ids() grows as
@@ -306,7 +306,7 @@ class ContinuousServeEngine:
         if self.meshstate is not None:
             r = self.meshstate.rules
             hd_shards = r.axis_size(r.axis("cache_hd"))
-        self._kernel_plan = kdispatch.engine_plan(
+        self.kernel_plan = kdispatch.engine_plan(
             cfg, block_size=bs, hd_shards=hd_shards)
 
     # ------------------------------------------------------------------
@@ -316,6 +316,20 @@ class ContinuousServeEngine:
         """Place an engine register on device — replicated over the mesh
         when one is attached (host-mastered state is never sharded)."""
         return self.meshstate.put_replicated(x) if self.meshstate else x
+
+    def _upload(self, host):
+        """Place a host-built register on device from a PRIVATE copy.
+
+        A transfer may read its numpy source after ``device_put`` returns
+        (XLA:CPU aliases aligned buffers or copies asynchronously), and the
+        engine edits its host mirrors (``_active``, ``_tables``) in place
+        right after a dispatch.  Uploading the live buffer let an in-flight
+        burst see its slot frozen early, and that stream then repeated one
+        token to the end.  The copy belongs to JAX alone.
+        """
+        host = np.array(host)
+        return jax.device_put(
+            host, self.meshstate.replicated if self.meshstate else None)
 
     def _with_rules(self):
         return (use_rules(self.meshstate.rules) if self.meshstate
@@ -327,7 +341,7 @@ class ContinuousServeEngine:
         the backend that actually ran is readable in the merged trace."""
         if not self._has_paged:
             return  # no attention layers -> no attention dispatch
-        d = self._kernel_plan[variant]
+        d = self.kernel_plan[variant]
         counts = self.stats["kernel_dispatch"]
         counts[d.tag] = counts.get(d.tag, 0) + 1
         if self.tracer is not None:
@@ -480,8 +494,8 @@ class ContinuousServeEngine:
         while n < len(pairs):
             n *= 2
         pairs = pairs + [(NULL_BLOCK, NULL_BLOCK)] * (n - len(pairs))
-        src = self._dev(jnp.asarray([p[0] for p in pairs], jnp.int32))
-        dst = self._dev(jnp.asarray([p[1] for p in pairs], jnp.int32))
+        src = self._upload(np.asarray([p[0] for p in pairs], np.int32))
+        dst = self._upload(np.asarray([p[1] for p in pairs], np.int32))
         with self._with_rules():
             self._caches = self._copy_blocks(self._caches, src, dst)
 
@@ -1018,10 +1032,10 @@ class ContinuousServeEngine:
                        else jax.random.fold_in(self._key, self._dispatches))
                 self._dispatches += 1
                 if self._active_dirty:
-                    self._active_dev = self._dev(jnp.asarray(self._active))
+                    self._active_dev = self._upload(self._active)
                     self._active_dirty = False
                 if self._tables_dirty:
-                    self._tables_dev = self._dev(jnp.asarray(self._tables))
+                    self._tables_dev = self._upload(self._tables)
                     self._tables_dirty = False
                 t_dispatch = _now_ns()
                 with (tr.phase(ev.PHASE_DECODE) if tr else contextlib.nullcontext()), \

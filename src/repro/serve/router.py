@@ -50,9 +50,15 @@ Tracing: the router is TASK 0 of a ``host_device`` process model spanning
 :meth:`close` the workers flush per-task segment streams and the router
 k-way merges them with its own records into ONE ``.prv`` — every replica
 is a row group in the same Paraver timeline (docs/router.md).
+
+One chip belongs to one process: on a TPU host each replica process is
+pinned to its own chip (:func:`replica_envs`), more replicas than chips is
+refused, and a router whose own process already holds the TPU refuses to
+start children that would need it.
 """
 from __future__ import annotations
 
+import glob
 import os
 import pathlib
 import shutil
@@ -171,6 +177,59 @@ class ReplicaHandle:
         self.proc.wait()
 
 
+# device files of attached TPU chips (v4 exposes accel nodes, v5e+ VFIO)
+TPU_CHIP_GLOBS = ("/dev/accel[0-9]*", "/dev/vfio/[0-9]*")
+TPU_PORT_BASE = 8476  # per-replica libtpu mesh-controller port
+
+
+def local_tpu_chips() -> int:
+    """TPU chips attached to this host, counted from their device files —
+    never through JAX, whose backend init would take the chips."""
+    return sum(len(glob.glob(pat)) for pat in TPU_CHIP_GLOBS)
+
+
+def _parent_holds_tpu() -> bool:
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return (xla_bridge.backends_are_initialized()
+            and "tpu" in xla_bridge.backends())
+
+
+def replica_envs(num_replicas: int, env: dict,
+                 chips: int | None = None) -> list[dict]:
+    """One environment per replica process.
+
+    Children pinned to the CPU (``JAX_PLATFORMS=cpu``), or on a host with no
+    TPU, share ``env``.  Otherwise replica ``r`` sees chip ``r`` alone
+    through libtpu's per-process chip-visibility settings.  More replicas
+    than chips is an error, and so is a parent process that holds the TPU
+    itself: either would leave two processes on one chip, which fail or
+    hang.
+    """
+    platforms = env.get("JAX_PLATFORMS", "")
+    chips = local_tpu_chips() if chips is None else chips
+    if platforms == "cpu" or chips == 0:
+        return [dict(env) for _ in range(num_replicas)]
+    if num_replicas > chips:
+        raise RuntimeError(
+            f"{num_replicas} replicas need one TPU chip each, but this host "
+            f"has {chips}; serve fewer replicas (or set JAX_PLATFORMS=cpu "
+            f"in worker_env to run them on the CPU)")
+    if _parent_holds_tpu():
+        raise RuntimeError(
+            "this process already holds the TPU, so its replica processes "
+            "could not open their chips; start the Router before anything "
+            "initializes JAX on the TPU")
+    return [{**env, "TPU_VISIBLE_CHIPS": str(r),
+             "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+             "TPU_PROCESS_BOUNDS": "1,1,1",
+             "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{TPU_PORT_BASE + r}",
+             "TPU_MESH_CONTROLLER_PORT": str(TPU_PORT_BASE + r)}
+            for r in range(num_replicas)]
+
+
 class Router:
     """Front-end router over N replica subprocesses (see module docstring).
 
@@ -248,6 +307,7 @@ class Router:
         env["PYTHONPATH"] = src + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
         env.update(worker_env or {})
+        envs = replica_envs(num_replicas, env)
 
         self.handles: list[ReplicaHandle] = []
         self.pending: list[dict[int, Request]] = []  # per replica: grid -> req
@@ -266,7 +326,7 @@ class Router:
             if trace:
                 cmd += ["--trace-base", str(self.trace_dir / f"replica{r}")]
             proc = subprocess.Popen(cmd, stdin=subprocess.PIPE,
-                                    stdout=subprocess.PIPE, env=env)
+                                    stdout=subprocess.PIPE, env=envs[r])
             h = ReplicaHandle(r, 1 + r, proc, role)
             ekw = dict(engine)
             ekw.update((per_replica or {}).get(r, {}))

@@ -474,10 +474,10 @@ class UnifiedServeEngine(ContinuousServeEngine):
                else jax.random.fold_in(self._key, self._dispatches))
         self._dispatches += 1
         if self._active_dirty:
-            self._active_dev = self._dev(jnp.asarray(self._active))
+            self._active_dev = self._upload(self._active)
             self._active_dirty = False
         if self._tables_dirty:
-            self._tables_dev = self._dev(jnp.asarray(self._tables))
+            self._tables_dev = self._upload(self._tables)
             self._tables_dirty = False
         rows = self.chunk_rows
         ck_tokens = np.zeros((rows, self.chunk_size), np.int32)
@@ -508,11 +508,11 @@ class UnifiedServeEngine(ContinuousServeEngine):
                     "unified", self._unified,
                     (self.params, self._caches, self._tok, self._idx,
                      self._active_dev, self._tables_dev,
-                     self._dev(jnp.asarray(ck_tokens)),
-                     self._dev(jnp.asarray(ck_start)),
-                     self._dev(jnp.asarray(ck_len)),
-                     self._dev(jnp.asarray(ck_slot)),
-                     self._dev(jnp.asarray(ck_sample)), key),
+                     self._upload(ck_tokens),
+                     self._upload(ck_start),
+                     self._upload(ck_len),
+                     self._upload(ck_slot),
+                     self._upload(ck_sample), key),
                     {"steps": steps, "chunk": bool(chunks)})
         if pairs:
             self._note_kernel("paged_decode")  # decode sub-batch scan
@@ -840,14 +840,14 @@ class UnifiedServeEngine(ContinuousServeEngine):
                     "spec", self._spec_step,
                     (self.params, self._caches, self._tok, self._idx,
                      self._active_dev, self._tables_dev,
-                     self._dev(jnp.asarray(drafts_all)),
-                     None if q_all is None else self._dev(jnp.asarray(q_all)),
-                     self._dev(jnp.asarray(spec_len)),
-                     self._dev(jnp.asarray(ck_tokens)),
-                     self._dev(jnp.asarray(ck_start)),
-                     self._dev(jnp.asarray(ck_len)),
-                     self._dev(jnp.asarray(ck_slot)),
-                     self._dev(jnp.asarray(ck_sample)), key),
+                     self._upload(drafts_all),
+                     None if q_all is None else self._dev(q_all),
+                     self._upload(spec_len),
+                     self._upload(ck_tokens),
+                     self._upload(ck_start),
+                     self._upload(ck_len),
+                     self._upload(ck_slot),
+                     self._upload(ck_sample), key),
                     {"chunk": bool(chunks)})
                 out, nacc, ck = jax.device_get((out_toks, n_acc, ck_fan))
             self._note_kernel("paged_span")  # draft/verify rides the span
@@ -989,7 +989,7 @@ class UnifiedServeEngine(ContinuousServeEngine):
                  else contextlib.nullcontext()), self._with_rules():
             self._caches, val, ids = self._beam_prefill(
                 self.params, self._caches, jnp.asarray(prompt),
-                self._dev(jnp.asarray(tables[0])), width=w)
+                self._upload(tables[0]), width=w)
         val, ids = np.asarray(val, np.float64), np.asarray(ids)
         self._note_kernel("paged_span")
         self.stats["host_syncs"] += 1
@@ -999,7 +999,7 @@ class UnifiedServeEngine(ContinuousServeEngine):
         idx = np.zeros((self.num_slots,), np.int32)
         active = np.zeros((self.num_slots,), bool)
         tok[:w], idx[:w], active[:w] = ids, plen, True
-        active_dev = self._dev(jnp.asarray(active))
+        active_dev = self._upload(active)
         # num_tokens - 1 decode steps: the final token's KV is never
         # written, so its position needs no block and triggers no CoW
         for step in range(1, num_tokens):
@@ -1028,9 +1028,9 @@ class UnifiedServeEngine(ContinuousServeEngine):
                     (tr.user_function(name="beam_step") if tr
                      else contextlib.nullcontext()), self._with_rules():
                 self._caches, val, ids = self._beam_step(
-                    self.params, self._caches, self._dev(jnp.asarray(tok)),
-                    self._dev(jnp.asarray(idx)), active_dev,
-                    self._dev(jnp.asarray(tables)), width=w)
+                    self.params, self._caches, self._upload(tok),
+                    self._upload(idx), active_dev,
+                    self._upload(tables), width=w)
             val = np.asarray(val, np.float64)[:w]
             ids = np.asarray(ids)[:w]
             self._note_kernel("paged_decode")

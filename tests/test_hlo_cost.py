@@ -73,9 +73,7 @@ def test_real_scan_vs_ground_truth():
     dot_flops = 3 * L * 2 * B * D * D
     assert hc.flops == pytest.approx(dot_flops, rel=0.15)  # + elementwise
     # XLA's built-in analysis undercounts by ~L
-    from repro.compat import cost_analysis_dict
-
-    xla = cost_analysis_dict(c).get("flops", 0)
+    xla = (c.cost_analysis() or {}).get("flops", 0)
     assert hc.flops > 3 * xla
 
 

@@ -47,6 +47,10 @@ def test_dispatch_rule_table():
     assert _resolve("auto", platform="tpu").backend == "pallas"
     assert _resolve("auto", platform="cpu").backend == "xla"
     assert "no Mosaic" in _resolve("auto", platform="cpu").reason
+    # ... and on TPU an auto-mode fallback is an error, not a quiet XLA run
+    assert _resolve("auto", platform="cpu", head_dim=20).backend == "xla"
+    with pytest.raises(dispatch.KernelFallbackError, match="head_dim 20"):
+        _resolve("auto", platform="tpu", head_dim=20)
     # pallas: forced even off-TPU (interpret mode), but never for
     # unsupported dtype / non-lane-tileable head_dim / vetoed call sites
     assert _resolve("pallas", platform="cpu").backend == "pallas"
@@ -86,10 +90,17 @@ def test_config_zoo_dispatches_pallas_on_tpu():
         plan = dispatch.engine_plan(cfg, block_size=16, platform="tpu")
         for variant, decision in plan.items():
             assert decision.backend == "pallas", (name, variant, decision)
-    # and head-dim sharding vetoes it, with the reason preserved
-    plan = dispatch.engine_plan(get_config("granite-8b"), block_size=16,
-                                hd_shards=2, platform="tpu")
-    assert all(d.backend == "xla" for d in plan.values())
+    # head-dim sharding vetoes it: on TPU in auto mode that is an error
+    # naming the reason, never a silent XLA fallback; mode=pallas keeps the
+    # per-variant fallback with the reason preserved
+    with pytest.raises(dispatch.KernelFallbackError, match="sharded 2-way"):
+        dispatch.engine_plan(get_config("granite-8b"), block_size=16,
+                             hd_shards=2, platform="tpu")
+    plan = dispatch.engine_plan(
+        get_config("granite-8b").replace(kernel_mode="pallas"),
+        block_size=16, hd_shards=2, platform="tpu")
+    assert all(d.backend == "xla" and "sharded" in d.reason
+               for d in plan.values())
 
 
 # ----------------------------------------------------------------------

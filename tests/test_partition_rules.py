@@ -3,9 +3,8 @@ per-shape behaviour — no devices needed (pure PartitionSpec logic)."""
 from __future__ import annotations
 
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.compat import make_abstract_mesh
 from repro.configs import SHAPES, get_config
 from repro.sharding.partition import Rules, constrain, make_rules, padded_vocab, use_rules
 
@@ -13,7 +12,7 @@ from repro.sharding.partition import Rules, constrain, make_rules, padded_vocab,
 @pytest.fixture(scope="module")
 def mesh():
     # abstract mesh: no devices touched
-    return make_abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_padded_vocab():
@@ -88,7 +87,7 @@ def test_overrides_validated(mesh):
 
 
 def test_multipod_axes():
-    mesh3 = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     cfg = get_config("granite-8b")
     r = make_rules(cfg, mesh3, SHAPES["train_4k"])
     assert r.mapping["act_batch"] == ("pod", "data")

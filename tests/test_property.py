@@ -207,8 +207,9 @@ def test_paged_span_attend_matches_dense_oracle(data):
     real_w = np.zeros(b, np.int64)
     tables = np.full((b, w), NULL_BLOCK, np.int32)
     for i in range(b):
-        row_len[i] = data.draw(st.integers(0, q_width))
-        hi = max(cap - int(row_len[i]), 0)
+        # a span never outgrows its slot's capacity (w blocks of bs)
+        row_len[i] = data.draw(st.integers(0, min(q_width, cap)))
+        hi = cap - int(row_len[i])
         row_start[i] = data.draw(st.integers(0, hi))
         end = int(row_start[i]) + int(row_len[i])
         # enough real blocks to hold the span; the rest stay NULL padding
@@ -223,7 +224,7 @@ def test_paged_span_attend_matches_dense_oracle(data):
     v_new = rng.standard_normal((b, q_width, kh, d)).astype(np.float32)
     positions = row_start[:, None] + np.arange(q_width, dtype=np.int32)[None]
 
-    cfg = types.SimpleNamespace(kernel_mode="xla")
+    cfg = types.SimpleNamespace(kernel_mode="xla", kv_dtype="fp16")
     out, new_cache = _paged_span_attend(
         jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
         {"k": jnp.asarray(pool_k), "v": jnp.asarray(pool_v)},
@@ -306,7 +307,7 @@ def test_kernel_fallback_never_changes_numerics(data):
 
     outs = {}
     for mode in ("xla", "pallas"):
-        cfg = types.SimpleNamespace(kernel_mode=mode)
+        cfg = types.SimpleNamespace(kernel_mode=mode, kv_dtype="fp16")
         o, _ = _paged_span_attend(
             jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
             {"k": jnp.asarray(pool_k), "v": jnp.asarray(pool_v)},

@@ -10,7 +10,7 @@ import pytest
 from repro.core import events as ev
 from repro.core.paraver import parse_prv
 from repro.serve.queue import RequestQueue
-from repro.serve.router import PrefixAffinity, Router
+from repro.serve.router import PrefixAffinity, Router, replica_envs
 
 # workers are their own jax processes — force the CPU backend and keep
 # compiles single-device regardless of what the host test process does
@@ -42,6 +42,33 @@ def _oracle(prompts, gen, *, kv_dtype=None, seed=2205):
     reqs = [eng.submit(p, gen) for p in prompts]
     out = eng.run()
     return [out[r.rid] for r in reqs]
+
+
+# ----------------------------------------------------------------------
+# one process per chip: replica environments, subprocess-free
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("platforms,chips", [("cpu", 4), ("", 0)])
+def test_replica_envs_share_env_without_a_chip(platforms, chips):
+    env = {"JAX_PLATFORMS": platforms, "KEEP": "1"}
+    envs = replica_envs(3, env, chips=chips)
+    assert envs == [env] * 3
+    assert all(e is not env for e in envs)
+
+
+def test_replica_envs_pin_one_chip_per_replica():
+    envs = replica_envs(4, {"KEEP": "1"}, chips=4)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert all(e["KEEP"] == "1" and e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+               and e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+               for e in envs)
+    ports = {e["TPU_MESH_CONTROLLER_PORT"] for e in envs}
+    assert len(ports) == 4  # no two replicas share a controller
+
+
+def test_replica_envs_refuse_more_replicas_than_chips():
+    with pytest.raises(RuntimeError, match="2 replicas need one TPU chip "
+                                           "each, but this host has 1"):
+        replica_envs(2, {}, chips=1)
 
 
 # ----------------------------------------------------------------------

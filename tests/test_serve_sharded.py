@@ -352,11 +352,11 @@ def test_spec_overlap_and_comm_counter_balance():
 RULES_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    from repro.compat import make_abstract_mesh
+    from jax.sharding import AbstractMesh
     from repro.configs import get_config, reduced
     from repro.sharding.partition import make_serve_rules
 
-    mesh = make_abstract_mesh((1, 2), ("data", "model"))
+    mesh = AbstractMesh((1, 2), ("data", "model"))
     # kv divisible -> pooled KV kv-head sharded, scheduler state replicated
     cfg = reduced(get_config("granite-8b"), num_layers=2, num_kv_heads=2)
     r = make_serve_rules(cfg, mesh)
@@ -369,7 +369,7 @@ RULES_SCRIPT = textwrap.dedent("""
     assert r1.mapping["kv_heads"] is None and r1.mapping["cache_hd"] == "model"
     # nothing shardable -> loud failure before any compile (padded vocab is
     # always 128-aligned, so an odd model extent is what exposes this)
-    mesh3 = make_abstract_mesh((1, 3), ("data", "model"))
+    mesh3 = AbstractMesh((1, 3), ("data", "model"))
     try:
         make_serve_rules(cfg1, mesh3)
     except ValueError as e:
