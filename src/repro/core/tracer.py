@@ -13,11 +13,25 @@ Device-side communication records cannot be intercepted on TPU like
 LD_PRELOADed MPI; they are *injected* from the compiled HLO's collective
 schedule (core/hlo_comm.py) anchored to measured step windows — see
 DESIGN.md section 2.
+
+One span, two sinks: :func:`span` writes a region into the JAX profiler's
+trace (a ``TraceAnnotation``, next to the device's ops) and, when a
+``Tracer`` is bound, the same name as an ``EV_USER_FUNC`` region into the
+``.prv``.  The two clocks differ: records here are ``perf_counter_ns()``
+less ``t0``, profiler events are relative to the profiler session.
+``Tracer.init`` bridges them with a zero-length ``tracer.anchor`` span
+whose ``mono_ns`` stat is the ``perf_counter_ns()`` it started at (``t0``,
+unless ``t0_ns`` was pinned): a profiler event at ``t`` happened at
+``perf_counter_ns() == t - anchor.start + mono_ns``, so at ``.prv`` time
+``t - anchor.start + mono_ns - t0``, which is ``t - anchor.start`` for an
+unpinned ``t0``.  The anchor is written only if a profiler session is
+collecting when ``init`` runs.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
+import sys
 import threading
 import time
 from pathlib import Path
@@ -76,6 +90,13 @@ class Tracer:
         if mode is not None:
             self.pm.set_mode(mode)
         self.t0 = _now() if t0_ns is None else int(t0_ns)
+        # no JAX imported, no profiler session to anchor (and none imported
+        # here: the tracer stays free of JAX)
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None:
+            mono = self.t0 if t0_ns is None else _now()
+            with profiler.TraceAnnotation("tracer.anchor", mono_ns=mono):
+                pass
         self._active = True
         self._open_state(ev.STATE_RUNNING)
         # anchor the base state exactly at t0 so states partition the
@@ -427,6 +448,37 @@ class Tracer:
             t_end=max(self.t_end - self.t0, 1),
         )
         return sort_trace(trace)
+
+
+# ----------------------------------------------------------------------
+# spans: one call site, the profiler's trace and the .prv
+# ----------------------------------------------------------------------
+class span:
+    """A host region named ``name`` in the JAX profiler's trace, with
+    ``counts`` (ints) as its stats; a no-op of a few microseconds unless a
+    profiler session is collecting.  With a ``tracer`` bound it is
+    also that tracer's ``EV_USER_FUNC`` region of the same name.  See the
+    module docstring for putting both on one clock."""
+
+    __slots__ = ("_note", "_region")
+
+    def __init__(self, name: str, tracer: Tracer | None = None, **counts):
+        from jax.profiler import TraceAnnotation
+
+        self._note = TraceAnnotation(name, **counts)
+        self._region = (None if tracer is None
+                        else tracer._user_function_ctx(name))
+
+    def __enter__(self):
+        self._note.__enter__()
+        if self._region is not None:
+            self._region.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._region is not None:
+            self._region.__exit__(*exc)
+        self._note.__exit__(*exc)
 
 
 # ----------------------------------------------------------------------

@@ -136,4 +136,5 @@ def flash_attention_fwd(
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -150,6 +150,7 @@ def paged_decode_fwd(
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_tables, index, *operands)
 
 
@@ -285,4 +286,5 @@ def paged_span_fwd(
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, qg, d), q.dtype),
         interpret=interpret,
+        name="paged_span_attention",
     )(block_tables, row_start, row_len, *operands)

@@ -11,9 +11,14 @@ under the serve mesh (pooled K/V sharded on kv-heads / head_dim).
 Position convention (both full and ring caches): slot ``s`` of a
 capacity-``C`` cache holds absolute position ``p`` with ``p % C == s``,
 taking the greatest such ``p`` at or below the decode index.
+
+Every write into the paged pool runs under ``jax.named_scope("kv_write")``,
+so its device ops carry ``kv_write`` in their op-name path in a profiler
+trace.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import quant
@@ -61,8 +66,6 @@ def slot_cache_write(kc, vc, k_new, v_new, index, window: int | None):
     ``index`` [B] (continuous batching) lowers to a per-example one-hot
     select, which keeps the write batchable without scatter.
     """
-    import jax
-
     C = kc.shape[1]
     slot = ring_slot(index, C, window)
     if index.ndim == 0:
@@ -80,6 +83,7 @@ def slot_cache_write(kc, vc, k_new, v_new, index, window: int | None):
     return kc, vc
 
 
+@jax.named_scope("kv_write")
 def paged_span_write(kp, vp, k_new, v_new, block_tables, row_start, row_len):
     """Write a per-row query span into the pooled [NB, bs, Kh, D] layout.
 
@@ -128,6 +132,7 @@ def _span_dest(block_tables, row_start, row_len, q, bs):
     return jnp.where(valid, blk * bs + pos % bs, pos % bs).reshape(-1)
 
 
+@jax.named_scope("kv_write")
 def copy_pool_blocks(leaf, src, dst):
     """Copy whole pool blocks ``src[i] -> dst[i]`` within one pooled leaf.
 
@@ -152,6 +157,7 @@ def _scatter_pool(leaf, new_flat, dest, axes):
     return constrain(flat.reshape(leaf.shape), axes)
 
 
+@jax.named_scope("kv_write")
 def quantized_span_write(cache, k_new, v_new, block_tables, row_start, row_len,
                          kv_dtype: str):
     """paged_span_write for a quantized pool: quantize-on-write.
@@ -177,6 +183,7 @@ def quantized_span_write(cache, k_new, v_new, block_tables, row_start, row_len,
     return out
 
 
+@jax.named_scope("kv_write")
 def quantized_cache_write(cache, k_new, v_new, block_tables, index,
                           kv_dtype: str):
     """paged_cache_write for a quantized pool (one token per slot)."""
@@ -192,6 +199,7 @@ def quantized_cache_write(cache, k_new, v_new, block_tables, index,
     return out
 
 
+@jax.named_scope("kv_write")
 def paged_cache_write(kp, vp, k_new, v_new, block_tables, index):
     """Write one token per slot into the pooled [NB, bs, Kh, D] layout.
 

@@ -76,7 +76,9 @@ from repro.serve.scheduler import Scheduler
 from repro.sharding.overlap import plan_overlap, resolve_mode
 from repro.sharding.partition import make_serve_rules, use_rules
 
-EV_TOKENS_DECODED = 84_001  # user event: tokens decoded so far (one run)
+# user event: tokens decoded so far (one run), of the fixed-batch
+# ServeEngine; the continuous engines count EV_TOKENS_TOTAL instead
+EV_TOKENS_DECODED = 84_001
 
 SERVE_TASK_AXES = ("pod", "data")  # trace process model: TASK = data coord
 SERVE_THREAD_AXES = ("model",)  # THREAD = model coord
@@ -153,7 +155,6 @@ class ContinuousServeEngine:
         if flush_every and flush_base is None:
             raise ValueError("flush_every requires flush_base")
         if tracer is not None:
-            tracer.register(EV_TOKENS_DECODED, "Tokens decoded")
             tracer.register(ev.EV_TOKENS_TOTAL,
                             ev.SERVE_CTR_LABELS[ev.EV_TOKENS_TOTAL])
             tracer.register(ev.EV_REQ_TTFT_US, ev.SERVE_CTR_LABELS[ev.EV_REQ_TTFT_US])
@@ -963,7 +964,6 @@ class ContinuousServeEngine:
         # records to disk instead of growing the buffers unbounded
         self._since_flush += max(len(toks), 1)
         if tr:
-            tr.emit(EV_TOKENS_DECODED, self.stats["tokens_decoded"])
             tr.emit(ev.EV_TOKENS_TOTAL, self.stats["tokens_decoded"])
             tr.emit(ev.EV_QUEUE_DEPTH, len(self.queue))
             if self.flush_every and self._since_flush >= self.flush_every:

@@ -74,8 +74,9 @@ import numpy as np
 
 from repro.core import events as ev
 from repro.core.sampling import fork_key, sample_logits, spec_accept
+from repro.core.tracer import span
 from repro.serve.block_pool import NULL_BLOCK
-from repro.serve.engine import EV_TOKENS_DECODED, ContinuousServeEngine
+from repro.serve.engine import ContinuousServeEngine
 from repro.serve.queue import Request, _now_ns
 
 
@@ -217,19 +218,22 @@ class UnifiedServeEngine(ContinuousServeEngine):
         bt = (jnp.where(active[:, None], tables, NULL_BLOCK)
               if self._has_paged else None)
         if steps:
-            caches, tok, idx, toks = self._decode_scan(
-                params, caches, tok, idx, active, bt, key, steps)
+            with jax.named_scope("decode"):
+                caches, tok, idx, toks = self._decode_scan(
+                    params, caches, tok, idx, active, bt, key, steps)
         else:
             toks = jnp.zeros((0, self.num_slots), jnp.int32)
 
         ck_fan = jnp.zeros(ck_start.shape + (self.num_slots,), jnp.int32)
         if chunk:
-            ck_tables = tables[ck_slot]  # [C, W]
-            caches, logits = self.model.span_step(
-                params, caches, ck_tokens, ck_start, ck_len, ck_tables,
-                micro_batches=self.overlap.micro_batches)
-            tok, idx, ck_fan = self._fold_chunk_rows(
-                logits, ck_start, ck_len, ck_slot, ck_sample, key, tok, idx)
+            with jax.named_scope("chunk"):
+                ck_tables = tables[ck_slot]  # [C, W]
+                caches, logits = self.model.span_step(
+                    params, caches, ck_tokens, ck_start, ck_len, ck_tables,
+                    micro_batches=self.overlap.micro_batches)
+                tok, idx, ck_fan = self._fold_chunk_rows(
+                    logits, ck_start, ck_len, ck_slot, ck_sample, key, tok,
+                    idx)
         return caches, tok, idx, toks, ck_fan
 
     def _fold_chunk_rows(self, logits, ck_start, ck_len, ck_slot, ck_sample,
@@ -301,28 +305,32 @@ class UnifiedServeEngine(ContinuousServeEngine):
             row_start = jnp.concatenate([idx, ck_start])
             row_len = jnp.concatenate([spec_len, ck_len])
             row_bt = jnp.concatenate([spec_bt, tables[ck_slot]])
-        caches, logits = self.model.span_step(
-            params, caches, row_tokens, row_start, row_len, row_bt,
-            micro_batches=self.overlap.micro_batches)
+        # the verify pass is the decode lane's (chunk rows ride it)
+        with jax.named_scope("decode"):
+            caches, logits = self.model.span_step(
+                params, caches, row_tokens, row_start, row_len, row_bt,
+                micro_batches=self.overlap.micro_batches)
 
-        k_acc = (key if self.temperature <= 0.0
-                 else jax.random.fold_in(key, 1 << 17))
-        out_toks, n_acc = spec_accept(
-            logits[:s, :kmax + 1], drafts, jnp.maximum(spec_len - 1, 0),
-            draft_q, k_acc, self.temperature, self.cfg.vocab_size,
-            self.top_k, self.top_p)
-        # belt-and-braces: gate on `active` too, so a slot whose span was
-        # dropped host-side after planning can never advance its registers
-        spec_active = (spec_len > 0) & active
-        final = jnp.take_along_axis(out_toks, n_acc[:, None], axis=1)[:, 0]
-        tok = jnp.where(spec_active, final, tok)
-        idx = jnp.where(spec_active, idx + n_acc + 1, idx)
+            k_acc = (key if self.temperature <= 0.0
+                     else jax.random.fold_in(key, 1 << 17))
+            out_toks, n_acc = spec_accept(
+                logits[:s, :kmax + 1], drafts, jnp.maximum(spec_len - 1, 0),
+                draft_q, k_acc, self.temperature, self.cfg.vocab_size,
+                self.top_k, self.top_p)
+            # belt-and-braces: gate on `active` too, so a slot whose span
+            # was dropped host-side after planning can never advance its
+            # registers
+            spec_active = (spec_len > 0) & active
+            final = jnp.take_along_axis(out_toks, n_acc[:, None], axis=1)[:, 0]
+            tok = jnp.where(spec_active, final, tok)
+            idx = jnp.where(spec_active, idx + n_acc + 1, idx)
 
         ck_fan = jnp.zeros(ck_start.shape + (self.num_slots,), jnp.int32)
         if chunk:
-            tok, idx, ck_fan = self._fold_chunk_rows(
-                logits[s:, :self.chunk_size], ck_start, ck_len, ck_slot,
-                ck_sample, key, tok, idx)
+            with jax.named_scope("chunk"):
+                tok, idx, ck_fan = self._fold_chunk_rows(
+                    logits[s:, :self.chunk_size], ck_start, ck_len, ck_slot,
+                    ck_sample, key, tok, idx)
         return caches, tok, idx, out_toks, n_acc, ck_fan
 
     # ------------------------------------------------------------------
@@ -493,65 +501,71 @@ class UnifiedServeEngine(ContinuousServeEngine):
             ck_sample[i] = c.sample
         return key, ck_tokens, ck_start, ck_len, ck_slot, ck_sample
 
+    def _phase(self, phase_id: int, on: bool = True):
+        """The tracer's ``EV_PHASE`` region, where a tracer is bound and
+        ``on`` holds."""
+        tr = self.tracer
+        return tr.phase(phase_id) if tr and on else contextlib.nullcontext()
+
     def _dispatch(self, pairs, steps, chunks: list[ChunkPlan]):
         tr = self.tracer
         if not pairs and not chunks:
             return None
-        key, ck_tokens, ck_start, ck_len, ck_slot, ck_sample = \
-            self._prep_dispatch(chunks)
-        t_dispatch = _now_ns()
-        with (tr.phase(ev.PHASE_DECODE) if tr else contextlib.nullcontext()), \
-                (tr.user_function(name="unified_step") if tr
-                 else contextlib.nullcontext()):
-            (self._caches, self._tok, self._idx, toks, ck_fan), coll_ops = \
-                self._traced_call(
-                    "unified", self._unified,
-                    (self.params, self._caches, self._tok, self._idx,
-                     self._active_dev, self._tables_dev,
-                     self._upload(ck_tokens),
-                     self._upload(ck_start),
-                     self._upload(ck_len),
-                     self._upload(ck_slot),
-                     self._upload(ck_sample), key),
-                    {"steps": steps, "chunk": bool(chunks)})
-        if pairs:
-            self._note_kernel("paged_decode")  # decode sub-batch scan
-        if steps:
-            # mirrors decode_syncs exactly: the fetch side bumps it iff this
-            # dispatch carried decode rows (tests assert the two stay equal)
-            self.stats["decode_dispatches"] += 1
-        if chunks:
-            self._note_kernel("paged_span")  # chunk rows run the span variant
-        for slot, req in pairs:
-            req.scheduled += steps
-            if req.scheduled >= req.max_new_tokens:
-                self._active[slot] = False
-                self._active_dirty = True
-        n_chunk = self._advance_chunks(chunks, t_dispatch, ck_fan)
         # per-ITERATION values (a burst is `steps` iterations in one
         # dispatch, emitted once; its chunks ride the first iteration):
         # STEP_BUDGET == CHUNK + DECODE at every sample, and chunkable
         # prefill never pushes it past max_step_tokens — whole-prompt
         # admissions (non-chunkable families, folded in here to keep the
         # triple cadence) are the documented budget bypass
-        n_chunk += self._whole_tokens
+        n_chunk = sum(c.length for c in chunks) + self._whole_tokens
         self._whole_tokens = 0
-        if tr:
-            tr.emit(ev.EV_STEP_BUDGET, len(pairs) + n_chunk)
-            tr.emit(ev.EV_CHUNK_TOKENS, n_chunk)
-            tr.emit(ev.EV_DECODE_TOKENS, len(pairs))
-        return toks, ck_fan, pairs, chunks, t_dispatch, coll_ops
+        with span("serve.unified_step", tr, steps=steps,
+                  decode_rows=len(pairs), chunk_tokens=n_chunk):
+            key, ck_tokens, ck_start, ck_len, ck_slot, ck_sample = \
+                self._prep_dispatch(chunks)
+            t_dispatch = _now_ns()
+            with self._phase(ev.PHASE_DECODE):
+                (self._caches, self._tok, self._idx, toks, ck_fan), \
+                    coll_ops = self._traced_call(
+                        "unified", self._unified,
+                        (self.params, self._caches, self._tok, self._idx,
+                         self._active_dev, self._tables_dev,
+                         self._upload(ck_tokens),
+                         self._upload(ck_start),
+                         self._upload(ck_len),
+                         self._upload(ck_slot),
+                         self._upload(ck_sample), key),
+                        {"steps": steps, "chunk": bool(chunks)})
+            if pairs:
+                self._note_kernel("paged_decode")  # decode sub-batch scan
+            if steps:
+                # mirrors decode_syncs exactly: the fetch side bumps it iff
+                # this dispatch carried decode rows (tests assert the two
+                # stay equal)
+                self.stats["decode_dispatches"] += 1
+            if chunks:
+                # chunk rows run the span variant
+                self._note_kernel("paged_span")
+            for slot, req in pairs:
+                req.scheduled += steps
+                if req.scheduled >= req.max_new_tokens:
+                    self._active[slot] = False
+                    self._active_dirty = True
+            self._advance_chunks(chunks, t_dispatch, ck_fan)
+            if tr:
+                tr.emit(ev.EV_STEP_BUDGET, len(pairs) + n_chunk)
+                tr.emit(ev.EV_CHUNK_TOKENS, n_chunk)
+                tr.emit(ev.EV_DECODE_TOKENS, len(pairs))
+            return toks, ck_fan, pairs, chunks, t_dispatch, coll_ops
 
     def _advance_chunks(self, chunks: list[ChunkPlan], t_dispatch,
-                        ck_fan=None) -> int:
+                        ck_fan=None) -> None:
         """Dispatch-side chunk bookkeeping (cursor advance, prompt-block
-        registration at completion, fan-out forking); returns the chunk
-        token count.  ``ck_fan`` is the dispatch's [C, S] sibling-token fan
-        — possibly still on device (pipelined unified path): the fork hook
-        seeds child registers from it without a host sync."""
-        n_chunk = 0
+        registration at completion, fan-out forking).  ``ck_fan`` is the
+        dispatch's [C, S] sibling-token fan — possibly still on device
+        (pipelined unified path): the fork hook seeds child registers from
+        it without a host sync."""
         for row, c in enumerate(chunks):
-            n_chunk += c.length
             slot, req = c.slot, c.req
             self._progress[slot] += c.length
             self.stats["prefill_tokens"] += c.length
@@ -575,7 +589,6 @@ class UnifiedServeEngine(ContinuousServeEngine):
                     # fork the siblings (a preemption-resumed parent keeps
                     # its existing forks — re-forking would double-serve)
                     self._fork_fanout(row, c, ck_fan, t_dispatch)
-        return n_chunk
 
     def _fork_fanout(self, row: int, c: ChunkPlan, ck_fan, t_dispatch):
         """Fan a completing fan-out prompt into its sibling decode streams.
@@ -669,9 +682,11 @@ class UnifiedServeEngine(ContinuousServeEngine):
         with the next step's device compute) and run retirement/latency
         bookkeeping — including the first tokens of prompts whose final
         chunks rode this step."""
-        toks, ck = jax.device_get((toks_dev, ck_dev))
-        self._process_tokens(toks, pairs, t_dispatch, coll_ops)
-        self._emit_chunk_tokens(chunks, ck)
+        with span("serve.fetch", self.tracer):
+            toks, ck = jax.device_get((toks_dev, ck_dev))
+        with span("serve.retire", self.tracer):
+            self._process_tokens(toks, pairs, t_dispatch, coll_ops)
+            self._emit_chunk_tokens(chunks, ck)
 
     # ------------------------------------------------------------------
     # speculative decoding (spec mode)
@@ -772,140 +787,147 @@ class UnifiedServeEngine(ContinuousServeEngine):
         done0 = len(self.scheduler.completed)
         t_run0 = time.perf_counter()
         while not self.scheduler.drained():
-            pairs = [(s, r) for s, r in self.scheduler.active()
-                     if self._active[s]]
-            pairs, spec_len = self._plan_spec(pairs)
-            decode_tokens = int(spec_len.sum())
-            if tr and (self.queue or self._prefilling.any()):
-                with tr.phase(ev.PHASE_ADMIT):
+            with span("serve.plan", tr):
+                pairs = [(s, r) for s, r in self.scheduler.active()
+                         if self._active[s]]
+                pairs, spec_len = self._plan_spec(pairs)
+                decode_tokens = int(spec_len.sum())
+                with self._phase(ev.PHASE_ADMIT,
+                                 bool(self.queue or self._prefilling.any())):
                     chunks = self._plan_chunks(pairs,
                                                decode_tokens=decode_tokens)
-            else:
-                chunks = self._plan_chunks(pairs, decode_tokens=decode_tokens)
-            # chunk planning can itself preempt a spec-planned decode victim
-            # (just-in-time chunk allocation, newest-first): drop the
-            # victim's span so the budget counters never charge positions
-            # that will not dispatch and its registers stay frozen
-            live = {s for s, _ in pairs}
-            for s in np.nonzero(spec_len)[0]:
-                if int(s) not in live:
-                    spec_len[s] = 0
-            decode_tokens = int(spec_len.sum())
-            self.stats["peak_active"] = max(self.stats["peak_active"],
-                                            self.scheduler.occupancy())
-            self.stats["peak_blocks"] = max(self.stats["peak_blocks"],
-                                            self.pool.num_active())
-            self.stats["peak_shared"] = max(self.stats["peak_shared"],
-                                            self.pool.num_shared())
-            if not pairs and not chunks:
-                if not self.scheduler.drained() and not self._preempted:
-                    if not self._relieve_stalled_prefill():
-                        raise RuntimeError(
-                            "serve loop stalled: nothing dispatchable but "
-                            "the scheduler is not drained")
-                self._drain_preempted()
-                continue
+                # chunk planning can itself preempt a spec-planned decode
+                # victim (just-in-time chunk allocation, newest-first): drop
+                # the victim's span so the budget counters never charge
+                # positions that will not dispatch and its registers stay
+                # frozen
+                live = {s for s, _ in pairs}
+                for s in np.nonzero(spec_len)[0]:
+                    if int(s) not in live:
+                        spec_len[s] = 0
+                decode_tokens = int(spec_len.sum())
+                self.stats["peak_active"] = max(self.stats["peak_active"],
+                                                self.scheduler.occupancy())
+                self.stats["peak_blocks"] = max(self.stats["peak_blocks"],
+                                                self.pool.num_active())
+                self.stats["peak_shared"] = max(self.stats["peak_shared"],
+                                                self.pool.num_shared())
+                if not pairs and not chunks:
+                    if not self.scheduler.drained() and not self._preempted:
+                        if not self._relieve_stalled_prefill():
+                            raise RuntimeError(
+                                "serve loop stalled: nothing dispatchable "
+                                "but the scheduler is not drained")
+                    self._drain_preempted()
+                    continue
 
-            # ---- host drafts from each slot's committed context ----
-            kmax = self.spec_k_max
-            drafts_all = np.zeros((self.num_slots, kmax), np.int32)
-            q_all = None
-            k_ask = max((int(spec_len[s]) - 1 for s, _ in pairs), default=0)
-            if k_ask > 0:
-                slots_ = [s for s, _ in pairs]
-                dr, q = self.spec.propose(
-                    slots_, [r.input_ids() for _, r in pairs], k_ask)
-                drafts_all[slots_, :k_ask] = dr[:, :k_ask]
-                if q is not None and self.temperature > 0.0:
-                    # device-side scatter: q may be a device array straight
-                    # from the draft model's propose scan
-                    q_all = jnp.zeros(
-                        (self.num_slots, kmax, self.cfg.vocab_size),
-                        jnp.float32)
-                    q_all = q_all.at[
-                        jnp.asarray(slots_, jnp.int32), :k_ask].set(
-                        jnp.asarray(q, jnp.float32)[:, :k_ask])
+                # ---- host drafts from each slot's committed context ----
+                kmax = self.spec_k_max
+                drafts_all = np.zeros((self.num_slots, kmax), np.int32)
+                q_all = None
+                k_ask = max((int(spec_len[s]) - 1 for s, _ in pairs),
+                            default=0)
+                if k_ask > 0:
+                    slots_ = [s for s, _ in pairs]
+                    dr, q = self.spec.propose(
+                        slots_, [r.input_ids() for _, r in pairs], k_ask)
+                    drafts_all[slots_, :k_ask] = dr[:, :k_ask]
+                    if q is not None and self.temperature > 0.0:
+                        # device-side scatter: q may be a device array
+                        # straight from the draft model's propose scan
+                        q_all = jnp.zeros(
+                            (self.num_slots, kmax, self.cfg.vocab_size),
+                            jnp.float32)
+                        q_all = q_all.at[
+                            jnp.asarray(slots_, jnp.int32), :k_ask].set(
+                            jnp.asarray(q, jnp.float32)[:, :k_ask])
 
             # ---- one span dispatch, fetched synchronously ----
-            self._flush_cow()  # CoW copies land before the span writes
-            key, ck_tokens, ck_start, ck_len, ck_slot, ck_sample = \
-                self._prep_dispatch(chunks)
-            t_dispatch = _now_ns()
-            with (tr.phase(ev.PHASE_DECODE) if tr
-                  else contextlib.nullcontext()), \
-                    (tr.user_function(name="spec_step") if tr
-                     else contextlib.nullcontext()):
-                (self._caches, self._tok, self._idx, out_toks, n_acc,
-                 ck_fan), coll_ops = self._traced_call(
-                    "spec", self._spec_step,
-                    (self.params, self._caches, self._tok, self._idx,
-                     self._active_dev, self._tables_dev,
-                     self._upload(drafts_all),
-                     None if q_all is None else self._dev(q_all),
-                     self._upload(spec_len),
-                     self._upload(ck_tokens),
-                     self._upload(ck_start),
-                     self._upload(ck_len),
-                     self._upload(ck_slot),
-                     self._upload(ck_sample), key),
-                    {"chunk": bool(chunks)})
-                out, nacc, ck = jax.device_get((out_toks, n_acc, ck_fan))
-            self._note_kernel("paged_span")  # draft/verify rides the span
-            self.stats["host_syncs"] += 1
-            self._replay(coll_ops, t_dispatch, _now_ns())
-            n_chunk = self._advance_chunks(chunks, t_dispatch, ck)
+            with span("serve.blocks", tr):
+                self._flush_cow()  # CoW copies land before the span writes
+            n_chunk = sum(c.length for c in chunks)
+            with self._phase(ev.PHASE_DECODE):
+                with span("serve.spec_step", tr, steps=1,
+                          decode_rows=len(pairs), chunk_tokens=n_chunk):
+                    key, ck_tokens, ck_start, ck_len, ck_slot, ck_sample = \
+                        self._prep_dispatch(chunks)
+                    t_dispatch = _now_ns()
+                    (self._caches, self._tok, self._idx, out_toks, n_acc,
+                     ck_fan), coll_ops = self._traced_call(
+                        "spec", self._spec_step,
+                        (self.params, self._caches, self._tok, self._idx,
+                         self._active_dev, self._tables_dev,
+                         self._upload(drafts_all),
+                         None if q_all is None else self._dev(q_all),
+                         self._upload(spec_len),
+                         self._upload(ck_tokens),
+                         self._upload(ck_start),
+                         self._upload(ck_len),
+                         self._upload(ck_slot),
+                         self._upload(ck_sample), key),
+                        {"chunk": bool(chunks)})
+                with span("serve.fetch", tr):
+                    out, nacc, ck = jax.device_get((out_toks, n_acc, ck_fan))
+            with span("serve.retire", tr):
+                self._note_kernel("paged_span")  # draft/verify rides the span
+                self.stats["host_syncs"] += 1
+                self._replay(coll_ops, t_dispatch, _now_ns())
+                self._advance_chunks(chunks, t_dispatch, ck)
 
-            # ---- commit accepted prefixes + correction/bonus tokens ----
-            drafted = accepted = 0
-            for slot, req in pairs:
-                if spec_len[slot] == 0:
-                    continue
-                m = int(nacc[slot]) + 1
-                drafted += int(spec_len[slot]) - 1
-                accepted += int(nacc[slot])
-                req.tokens.extend(int(t) for t in out[slot, :m])
-                req.scheduled = len(req.tokens)
-                self.stats["tokens_decoded"] += m
-                if len(req.tokens) >= req.max_new_tokens:
-                    self._finish(req)  # releases every block, garbage incl.
-                else:
-                    self._rollback_spec_blocks(slot, self._slot_pos(slot, req))
-            self._emit_chunk_tokens(chunks, ck)
-            self.stats["spec_dispatches"] += 1 if pairs else 0
-            self.stats["spec_drafted"] += drafted
-            self.stats["spec_accepted"] += accepted
-            if pairs:
-                self.stats["iterations"] += 1
-                self.stats["decode_syncs"] += 1
-                # the spec lane fetches synchronously, so dispatch and sync
-                # coincide — but the invariant stays the same
-                self.stats["decode_dispatches"] += 1
-            k_used = self._spec_k  # width actually in effect this dispatch
-            if drafted > 0:
-                self._accept_ema = (0.7 * self._accept_ema
-                                    + 0.3 * accepted / drafted)
-                if self.spec_adaptive:
-                    if self._accept_ema > 0.7:
-                        self._spec_k = min(self._spec_k + 1, self.spec_k_max)
-                    elif self._accept_ema < 0.35:
-                        self._spec_k = max(1, self._spec_k - 1)
-            self._since_flush += 1
-            if tr:
-                tr.emit(ev.EV_STEP_BUDGET, decode_tokens + n_chunk)
-                tr.emit(ev.EV_CHUNK_TOKENS, n_chunk)
-                tr.emit(ev.EV_DECODE_TOKENS, decode_tokens)
+                # ---- commit accepted prefixes + correction/bonus tokens ----
+                drafted = accepted = 0
+                for slot, req in pairs:
+                    if spec_len[slot] == 0:
+                        continue
+                    m = int(nacc[slot]) + 1
+                    drafted += int(spec_len[slot]) - 1
+                    accepted += int(nacc[slot])
+                    req.tokens.extend(int(t) for t in out[slot, :m])
+                    req.scheduled = len(req.tokens)
+                    self.stats["tokens_decoded"] += m
+                    if len(req.tokens) >= req.max_new_tokens:
+                        # releases every block, garbage incl.
+                        self._finish(req)
+                    else:
+                        self._rollback_spec_blocks(slot,
+                                                   self._slot_pos(slot, req))
+                self._emit_chunk_tokens(chunks, ck)
+                self.stats["spec_dispatches"] += 1 if pairs else 0
+                self.stats["spec_drafted"] += drafted
+                self.stats["spec_accepted"] += accepted
                 if pairs:
-                    tr.emit(ev.EV_SPEC_DRAFTED, drafted)
-                    tr.emit(ev.EV_SPEC_ACCEPTED, accepted)
-                    tr.emit(ev.EV_SPEC_K, k_used)
-                tr.emit(EV_TOKENS_DECODED, self.stats["tokens_decoded"])
-                tr.emit(ev.EV_TOKENS_TOTAL, self.stats["tokens_decoded"])
-                tr.emit(ev.EV_QUEUE_DEPTH, len(self.queue))
-                if self.flush_every and self._since_flush >= self.flush_every:
-                    tr.flush(self.flush_base,
-                             split_tasks=self.meshstate is not None)
-                    self._since_flush = 0
-            self._drain_preempted()
+                    self.stats["iterations"] += 1
+                    self.stats["decode_syncs"] += 1
+                    # the spec lane fetches synchronously, so dispatch and
+                    # sync coincide — but the invariant stays the same
+                    self.stats["decode_dispatches"] += 1
+                k_used = self._spec_k  # width actually in effect this dispatch
+                if drafted > 0:
+                    self._accept_ema = (0.7 * self._accept_ema
+                                        + 0.3 * accepted / drafted)
+                    if self.spec_adaptive:
+                        if self._accept_ema > 0.7:
+                            self._spec_k = min(self._spec_k + 1,
+                                               self.spec_k_max)
+                        elif self._accept_ema < 0.35:
+                            self._spec_k = max(1, self._spec_k - 1)
+                self._since_flush += 1
+                if tr:
+                    tr.emit(ev.EV_STEP_BUDGET, decode_tokens + n_chunk)
+                    tr.emit(ev.EV_CHUNK_TOKENS, n_chunk)
+                    tr.emit(ev.EV_DECODE_TOKENS, decode_tokens)
+                    if pairs:
+                        tr.emit(ev.EV_SPEC_DRAFTED, drafted)
+                        tr.emit(ev.EV_SPEC_ACCEPTED, accepted)
+                        tr.emit(ev.EV_SPEC_K, k_used)
+                    tr.emit(ev.EV_TOKENS_TOTAL, self.stats["tokens_decoded"])
+                    tr.emit(ev.EV_QUEUE_DEPTH, len(self.queue))
+                    if self.flush_every \
+                            and self._since_flush >= self.flush_every:
+                        tr.flush(self.flush_base,
+                                 split_tasks=self.meshstate is not None)
+                        self._since_flush = 0
+                self._drain_preempted()
         self.stats["seconds"] += time.perf_counter() - t_run0
         return {r.rid: np.asarray(r.tokens, np.int32)
                 for r in self.scheduler.completed[done0:]}
@@ -1091,38 +1113,35 @@ class UnifiedServeEngine(ContinuousServeEngine):
         inflight: collections.deque = collections.deque()
         t_run0 = time.perf_counter()
         while inflight or not self.scheduler.drained():
-            if not self.chunkable:
-                # state-carrying families: budget-looped whole-prompt
-                # admission through the inherited grouped-prefill path
-                if self.queue and tr:
-                    with tr.phase(ev.PHASE_ADMIT):
+            with span("serve.plan", tr):
+                if not self.chunkable:
+                    # state-carrying families: budget-looped whole-prompt
+                    # admission through the inherited grouped-prefill path
+                    with self._phase(ev.PHASE_ADMIT, bool(self.queue)):
                         admissions = self.scheduler.admissions()
-                else:
-                    admissions = self.scheduler.admissions()
-                for members in self._prefill_groups(admissions):
-                    # count BEFORE the prefill call: it appends the first
-                    # sampled token, growing input_ids()
-                    self._whole_tokens += sum(
-                        self._start_index(r) - r.prefix_hit_tokens
-                        for _, r in members)
-                    self._do_prefill(members)
-            pairs = [(s, r) for s, r in self.scheduler.active()
-                     if self._active[s]]
-            if self.chunkable and tr and (self.queue or self._prefilling.any()):
-                with tr.phase(ev.PHASE_ADMIT):
+                    for members in self._prefill_groups(admissions):
+                        # count BEFORE the prefill call: it appends the
+                        # first sampled token, growing input_ids()
+                        self._whole_tokens += sum(
+                            self._start_index(r) - r.prefix_hit_tokens
+                            for _, r in members)
+                        self._do_prefill(members)
+                pairs = [(s, r) for s, r in self.scheduler.active()
+                         if self._active[s]]
+                with self._phase(ev.PHASE_ADMIT, self.chunkable and bool(
+                        self.queue or self._prefilling.any())):
                     chunks = self._plan_chunks(pairs)
-            else:
-                chunks = self._plan_chunks(pairs)
-            pairs, steps = self._ensure_blocks(
-                pairs, max_steps=self.mixed_burst if chunks else None)
-            self._flush_cow()  # CoW copies land before the burst writes
-            self.stats["peak_active"] = max(self.stats["peak_active"],
-                                            self.scheduler.occupancy())
-            if self.pool is not None:
-                self.stats["peak_blocks"] = max(self.stats["peak_blocks"],
-                                                self.pool.num_active())
-                self.stats["peak_shared"] = max(self.stats["peak_shared"],
-                                                self.pool.num_shared())
+            with span("serve.blocks", tr):
+                pairs, steps = self._ensure_blocks(
+                    pairs, max_steps=self.mixed_burst if chunks else None)
+                self._flush_cow()  # CoW copies land before the burst writes
+                self.stats["peak_active"] = max(self.stats["peak_active"],
+                                                self.scheduler.occupancy())
+                if self.pool is not None:
+                    self.stats["peak_blocks"] = max(self.stats["peak_blocks"],
+                                                    self.pool.num_active())
+                    self.stats["peak_shared"] = max(self.stats["peak_shared"],
+                                                    self.pool.num_shared())
             dispatched = self._dispatch(pairs, steps, chunks)
             if dispatched is None and self._whole_tokens and tr:
                 # whole-prompt prefills with nothing left to decode (e.g.
@@ -1153,7 +1172,9 @@ class UnifiedServeEngine(ContinuousServeEngine):
                              and not self._preempted) else 0
             while len(inflight) > keep:
                 self._process_unified(*inflight.popleft())
-            self._drain_preempted()
+            if self._preempted:
+                with span("serve.retire", tr):
+                    self._drain_preempted()
         self.stats["seconds"] += time.perf_counter() - t_run0
         return {r.rid: np.asarray(r.tokens, np.int32)
                 for r in self.scheduler.completed[done0:]}
