@@ -3,8 +3,10 @@
 AttentionEngine-style policy search, scoped to what actually matters on
 TPU for these kernels: the block/grid/VMEM-tiling parameters
 (``block_q``/``block_k`` for dense flash, ``block_q`` over the folded
-query dim for the ragged span kernel; paged decode has a fixed tiling —
-one query token per slot — so its candidate set is the trivial one).
+query dim for the ragged span kernel; paged decode derives its tiling
+from the shapes it is given — table entries per grid step from the block
+size and table width, every kv head in one step — so its candidate set
+is the trivial one).
 
 Two layers:
 
@@ -117,7 +119,7 @@ def candidates_for(variant: str, *, head_dim: int) -> list[dict]:
     if variant == "paged_span":
         # tiles over the folded Q*G dim; None = one tile (no extra grid axis)
         return [{"block_q": None}, {"block_q": 16}, {"block_q": 64}]
-    return [{}]  # paged_decode: fixed tiling, one query token per slot
+    return [{}]  # paged_decode: tiling follows the shapes (ops.py)
 
 
 def default_params(variant: str) -> dict:

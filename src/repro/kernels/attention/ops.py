@@ -54,7 +54,7 @@ def flash_attention(
 
 def _scale_pages(cache):
     """Quantized pools: head-major [Hkv, NB, 1, bs] scale rows for the
-    kernels (empty kwargs for native pools — the static `quant` flag stays
+    span kernel (empty kwargs for native pools — the static `quant` flag stays
     False)."""
     if "k_scale" not in cache:
         return {}
@@ -62,12 +62,32 @@ def _scale_pages(cache):
             for n in ("k_scale", "v_scale")}
 
 
+# one grid step of the paged decode kernel walks at most this many tokens
+# of context, and its K/V blocks take at most this much VMEM: two buffers
+# of each operand in the pool's dtype, and the float32 copy its product reads
+DECODE_STEP_TOKENS = 512
+DECODE_STEP_VMEM = 8 << 20
+
+
+def decode_pages_per_step(block_size: int, table_width: int, kv_heads: int,
+                          head_dim: int, itemsize: int) -> int:
+    """Table entries per paged-decode grid step: the largest divisor of
+    the table width that keeps a step within ``DECODE_STEP_TOKENS`` and
+    its K/V blocks within ``DECODE_STEP_VMEM`` (head_dim padded to whole
+    128-lane tiles, as the kernel holds it)."""
+    lanes = -(-head_dim // 128) * 128
+    page = block_size * kv_heads * lanes * 2 * (2 * itemsize + 4)
+    most = max(1, min(table_width, DECODE_STEP_TOKENS // block_size,
+                      DECODE_STEP_VMEM // page))
+    return max(p for p in range(1, most + 1) if table_width % p == 0)
+
+
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_attention(cache, q, block_tables, index, *, window: int | None = None,
                     interpret: bool | None = None):
     """Paged decode.  cache: {"k","v"} [NB, bs, Hkv, D] pooled blocks
-    (engine layout); q: [B, 1, Hq, D]; block_tables: [B, W] int32;
-    index: [B] int32.
+    (engine layout, read in place); q: [B, 1, Hq, D]; block_tables: [B, W]
+    int32; index: [B] int32.
 
     interpret=None -> auto: Pallas interpret mode off-TPU (this container),
     compiled Mosaic kernel on TPU.  Returns [B, 1, Hq, D].
@@ -75,15 +95,15 @@ def paged_attention(cache, q, block_tables, index, *, window: int | None = None,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, _, hq, d = q.shape
-    hkv = cache["k"].shape[2]
-    g = hq // hkv
-    qt = q.reshape(b, hkv, g, d)  # q head h = kh*G + g_
-    kp = jnp.transpose(cache["k"], (2, 0, 1, 3))  # [Hkv, NB, bs, D]
-    vp = jnp.transpose(cache["v"], (2, 0, 1, 3))
+    _, bs, hkv, _ = cache["k"].shape
+    scales = ({"k_scales": cache["k_scale"], "v_scales": cache["v_scale"]}
+              if "k_scale" in cache else {})
     out = paged_decode_fwd(
-        qt, kp, vp, jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(index, jnp.int32), window=window, interpret=interpret,
-        **_scale_pages(cache),
+        q.reshape(b, hq, d), cache["k"], cache["v"],
+        jnp.asarray(block_tables, jnp.int32), jnp.asarray(index, jnp.int32),
+        pages=decode_pages_per_step(bs, block_tables.shape[1], hkv, d,
+                                    cache["k"].dtype.itemsize),
+        window=window, interpret=interpret, **scales,
     )
     return out.reshape(b, 1, hq, d)
 

@@ -520,7 +520,8 @@ class UnifiedServeEngine(ContinuousServeEngine):
         n_chunk = sum(c.length for c in chunks) + self._whole_tokens
         self._whole_tokens = 0
         with span("serve.unified_step", tr, steps=steps,
-                  decode_rows=len(pairs), chunk_tokens=n_chunk):
+                  decode_rows=len(pairs), chunk_tokens=n_chunk,
+                  kv_pages=self._decode_pages(pairs, steps)):
             key, ck_tokens, ck_start, ck_len, ck_slot, ck_sample = \
                 self._prep_dispatch(chunks)
             t_dispatch = _now_ns()
@@ -557,6 +558,27 @@ class UnifiedServeEngine(ContinuousServeEngine):
                 tr.emit(ev.EV_CHUNK_TOKENS, n_chunk)
                 tr.emit(ev.EV_DECODE_TOKENS, len(pairs))
             return toks, ck_fan, pairs, chunks, t_dispatch, coll_ops
+
+    def _decode_pages(self, pairs, steps: int) -> int:
+        """Table entries the paged decode kernel reads for this dispatch's
+        decode rows, summed over its ``steps`` iterations, in one
+        full-attention layer: a row at position p reads entries 0 ..
+        min(p // bs, W - 1) (windowed layers skip the stale ones)."""
+        if not self._has_paged:
+            return 0
+        bs, w = self.block_size, self._tables.shape[1]
+
+        def upto(n):  # entries read by positions 0 .. n - 1, summed
+            k, r = divmod(min(n, w * bs), bs)
+            return bs * k * (k + 1) // 2 + r * (k + 1) + max(n - w * bs, 0) * w
+
+        pages = 0
+        for slot, req in pairs:
+            # the row's first position this dispatch (as _ensure_blocks)
+            p = (int(self._slot_start[slot]) + req.scheduled
+                 - int(self._slot_sched0[slot]) - 1)
+            pages += upto(p + steps) - upto(max(p, 0))
+        return pages
 
     def _advance_chunks(self, chunks: list[ChunkPlan], t_dispatch,
                         ck_fan=None) -> None:

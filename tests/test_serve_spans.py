@@ -62,6 +62,17 @@ def profiled(request, tmp_path_factory):
         eng = UnifiedServeEngine(cfg, params, num_slots=2, max_len=64,
                                  block_size=8, chunk_size=8, tracer=tracer,
                                  **extra)
+        # what each decode dispatch's kernel will read: its rows and the
+        # slots' position registers on the device as it is dispatched
+        seen = []
+        dispatch = eng._dispatch
+
+        def recording(pairs, steps, chunks):
+            if pairs or chunks:  # else nothing is dispatched
+                seen.append(([s for s, _ in pairs], steps,
+                             np.asarray(eng._idx)))
+            return dispatch(pairs, steps, chunks)
+        eng._dispatch = recording
         for p in prompts:
             eng.submit(p, 6)
         eng.run()
@@ -69,6 +80,7 @@ def profiled(request, tmp_path_factory):
     finally:
         jax.profiler.stop_trace()
     path = glob.glob(f"{tmp}/**/*.xplane.pb", recursive=True)[0]
+    eng.dispatched = seen
     return request.param, _host_spans(path), tracer, trace, eng
 
 
@@ -98,6 +110,25 @@ def test_each_dispatch_runs_plan_blocks_dispatch_fetch_retire(profiled):
         assert sum(s["steps"] for s in stats) == eng.stats["iterations"]
     else:
         assert all(s["steps"] == 1 for s in stats)
+
+
+def test_unified_step_counts_the_decode_kernels_live_pages(profiled):
+    """``kv_pages``: the table entries the decode kernel reads per layer in
+    the dispatch, from the rows' device positions: a row at position p
+    reads entries 0 .. min(p // bs, W - 1) at each of the ``steps``."""
+    kind, spans, _, _, eng = profiled
+    if kind == "spec":  # verify spans run the span kernel: no such count
+        assert all("kv_pages" not in s[4] for s in spans
+                   if s[1] == "serve.spec_step")
+        return
+    stats = [s[4] for s in spans if s[1] == "serve.unified_step"]
+    assert len(stats) == len(eng.dispatched)
+    bs, w = eng.block_size, eng._tables.shape[1]
+    want = [sum(min((int(idx[s]) + t) // bs, w - 1) + 1
+                for s in rows for t in range(steps))
+            for rows, steps, idx in eng.dispatched]
+    assert [s["kv_pages"] for s in stats] == want
+    assert any(want)
 
 
 def test_prv_regions_align_with_profiler_spans(profiled):
