@@ -1,6 +1,8 @@
 """Find a cell's files by the names in ``BENCHMARK.json``.
 
-configs/<config>.json   the model, its cut, the weight map, the check limit
+configs/<config>.json   the model, its cut, its architecture, the weight map,
+                        the check limit
+arch/<reference>.py     the architecture a configuration names (``arch``)
 traffic/<traffic>.json  the mix: load parameters and deployment settings
 metrics/<metric>.py     one reader per metric: ``read(rec) -> float | None``
 peaks.json              the chip's published peaks, by ``device_kind``
@@ -13,6 +15,7 @@ import pathlib
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
+ARCH = HERE / "arch"
 
 
 def bench() -> dict:
@@ -29,6 +32,39 @@ def cell(name: str, spec: dict | None = None) -> dict:
 
 def config(name: str) -> dict:
     return json.loads((HERE / "configs" / f"{name}.json").read_text())
+
+
+def arch(name: str | None):
+    """The architecture module ``arch/<name>.py`` that a configuration
+    names under ``"reference"``.  It gives the shared code what depends on
+    the layers' mathematics (``arch/dense_gqa.py`` is the first):
+
+    - ``KEYS``: the configuration keys its reference reads;
+    - ``IDS``: {tensor name: draw id} of its layers' tensors, by the rule
+      in ``seedweights``;
+    - ``shapes(c)``: the shape of one draw of every tensor it draws, the
+      global ``embed``, ``lm_head`` and ``final_norm`` included;
+    - ``experts(c)``: {tensor name: experts} of the tensors drawn once per
+      published expert index;
+    - ``kind(c, i)``: layer i's kind, hashable; ``tensors(c, kind)``: the
+      tensors such a layer draws; ``forward(w, x, pos, kind, *, c, fp8,
+      qblk)``: its float32 forward of x [B, L, d] at positions pos [L],
+      matrix products through ``refmodel._mm``.  One compile per kind;
+    - ``program(c)``: {field of the program's config: the value it must
+      have}; ``tiny(c, dtype)``: the keys that cut the configuration to a
+      small CPU size, its ``program`` block among them;
+    - the work counts behind ``workcount``'s public functions, with their
+      signatures.
+    """
+    known = sorted(p.stem for p in ARCH.glob("*.py"))
+    if name not in known:
+        raise SystemExit(f"the configuration's \"reference\" names "
+                         f"architecture {name!r}; known: {known}")
+    sp = importlib.util.spec_from_file_location(f"chipbench_arch_{name}",
+                                                ARCH / f"{name}.py")
+    mod = importlib.util.module_from_spec(sp)
+    sp.loader.exec_module(mod)
+    return mod
 
 
 def mix(name: str) -> dict:

@@ -6,7 +6,8 @@ call; build the program's ``UnifiedServeEngine`` at the cell's deployment
 settings (``kernel_mode=auto``, compile cache in ``<checkout>/.jax_cache``);
 warm every step shape the traffic uses; drive the cell's traffic through
 ``submit``/``run`` and measure for ``--seconds``; stop the engine, free it,
-and compare a sample of the served tokens with the float32 reference.
+and compare a sample of the served tokens with the float32 reference of
+the configuration's architecture (``chipbench/arch/<reference>.py``).
 
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -97,9 +98,10 @@ SPANS = ("engine.run", "setup.sessions")
 
 
 def build_engine(c: dict, mx: dict, seed: int):
-    """The program at the configuration's widths and depth, with any other
-    fields of its config from the configuration file's ``program`` block,
-    and ``UnifiedServeEngine`` given the mix's ``engine`` block as keyword
+    """The program at the configuration's depth, with any other fields of
+    its config from the configuration file's ``program`` block, checked
+    against the widths its architecture reads from the file; and
+    ``UnifiedServeEngine`` given the mix's ``engine`` block as keyword
     arguments (the prefix cache on unless the block says otherwise)."""
     import jax
 
@@ -107,16 +109,13 @@ def build_engine(c: dict, mx: dict, seed: int):
     from repro.models.model import build_model
     from repro.serve.step import UnifiedServeEngine
 
+    import cellspec
     import seedweights as sw
 
-    z = sw.dims(c)
+    want = cellspec.arch(c.get("reference")).program(c)
     base = get_config(c["arch"])
-    cfg = base.replace(num_layers=z["layers"], kernel_mode="auto")
+    cfg = base.replace(num_layers=c["num_hidden_layers"], kernel_mode="auto")
     cfg = cfg.replace(**c.get("program", {}))
-    want = dict(d_model=z["d"], num_heads=z["h"], num_kv_heads=z["kh"],
-                head_dim=z["hd"], d_ff=z["ff"], vocab_size=z["v"],
-                dtype=c["torch_dtype"], norm_eps=z["eps"],
-                rope_theta=z["theta"])
     have = {k: getattr(cfg, k) for k in want}
     if have != want:
         raise SystemExit(f"program config {have} differs from {want}")
@@ -175,17 +174,20 @@ def request_rows(ctl, marks) -> list[dict]:
 
 def decode_work(c: dict, rows, key0: str, key1: str) -> dict:
     """Decoded tokens (each request's second token on) between two token
-    snapshots, with the sum of their attended lengths."""
+    snapshots, with the sum of their attended lengths and each row's
+    (context before its first decoded token, tokens decoded)."""
     import workcount as wc
 
     toks = ctx = 0
+    spans = []
     for r in rows:
         a, b = max(r[key0], 1), max(r[key1], 1)
         if b > a:
             toks += b - a
             # token j (1-based) attends prompt + j - 1 positions
-            ctx += wc.sum_contexts(r["prompt_len"] + a - 1, b - a)
-    return {"tokens": toks, "ctx_sum": ctx}
+            spans.append((r["prompt_len"] + a - 1, b - a))
+            ctx += wc.sum_contexts(*spans[-1])
+    return {"tokens": toks, "ctx_sum": ctx, "spans": spans}
 
 
 def pick_sample(handles, seed: int, want_tokens: int, max_total: int,

@@ -1,13 +1,14 @@
-"""Plain reference: a dense GQA decoder (Llama layout) in float32 jax.numpy.
+"""Plain reference in float32 jax.numpy: the part every architecture shares.
 
-Pre-norm RMSNorm, rotary embedding on q and k (halves rotated, as the
-Llama/Granite/Yi configurations state), grouped-query attention with a
-causal mask, SwiGLU MLP, untied LM head.  No kernel, cache or batching
-trick: each sequence attends over itself in full.  Weights are drawn from
-the seed by :mod:`seedweights`, one layer at a time, so that the whole
-reference never holds more than one layer of float32 weights; every matrix
-product runs at ``Precision.HIGHEST`` (on a TPU a float32 product otherwise
-runs in bfloat16 passes).
+The embedding, teacher forcing over padded rows, the final RMSNorm, the
+untied LM head and the control; each layer's tensors and forward are the
+configuration's architecture's (``chipbench/arch/<reference>.py``), one
+compiled forward per kind of layer.  No kernel, cache or batching trick:
+each sequence attends over itself.  Weights are drawn from the seed by
+:mod:`seedweights`, one layer at a time, so that the whole reference never
+holds more than one layer of float32 weights; every matrix product runs
+through :func:`_mm` at ``Precision.HIGHEST`` (on a TPU a float32 product
+otherwise runs in bfloat16 passes).
 
 ``control=True`` also runs the control beside it: the same forward with
 every matrix product's two operands rounded to float8 e4m3 (per output
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import cellspec
 import seedweights as sw
 
 HI = jax.lax.Precision.HIGHEST
@@ -46,66 +48,38 @@ def _rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
 
 
-def _rope(x, pos, theta):
-    """x [B, L, H, D]; positions [L]."""
-    half = x.shape[-1] // 2
-    freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = pos[:, None].astype(jnp.float32) * freq
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+def _arch(c: dict):
+    return cellspec.arch(c.get("reference"))
 
 
-def _attend(q, k, v, qblk):
-    """One sequence: q [L, Kh, G, D], k/v [L, Kh, D] -> [L, Kh, G, D],
-    causal, in query blocks of ``qblk`` rows."""
-    L, kh, g, d = q.shape
-    nb = L // qblk
-    qs = q.reshape(nb, qblk, kh, g, d)
-    t = jnp.arange(L)
-
-    def blk(args):
-        qi, i = args
-        s = jnp.einsum("qkgd,tkd->kgqt", qi, k, precision=HI) / np.sqrt(d)
-        rows = i * qblk + jnp.arange(qblk)
-        s = jnp.where(t[None, :] <= rows[:, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("kgqt,tkd->qkgd", p, v, precision=HI)
-
-    return jax.lax.map(blk, (qs, jnp.arange(nb))).reshape(L, kh, g, d)
-
-
-@functools.partial(jax.jit, static_argnames=("c", "fp8", "qblk"))
-def _layer(w, x, *, c, fp8, qblk):
-    z = sw.dims(dict(c))
-    B, L, _ = x.shape
-    pos = jnp.arange(L)
-    h = _rms(x, w["attn_norm"], z["eps"])
-    q = _mm(h, w["wq"], fp8).reshape(B, L, z["h"], z["hd"])
-    k = _mm(h, w["wk"], fp8).reshape(B, L, z["kh"], z["hd"])
-    v = _mm(h, w["wv"], fp8).reshape(B, L, z["kh"], z["hd"])
-    q, k = _rope(q, pos, z["theta"]), _rope(k, pos, z["theta"])
-    g = z["h"] // z["kh"]
-    o = jax.vmap(lambda a, b, cc: _attend(a, b, cc, qblk))(
-        q.reshape(B, L, z["kh"], g, z["hd"]), k, v)
-    x = x + _mm(o.reshape(B, L, z["h"] * z["hd"]), w["wo"], fp8)
-    h = _rms(x, w["mlp_norm"], z["eps"])
-    a = jax.nn.silu(_mm(h, w["w_gate"], fp8)) * _mm(h, w["w_up"], fp8)
-    return x + _mm(a, w["w_down"], fp8)
+@functools.partial(jax.jit, static_argnames=("c", "kind", "fp8", "qblk"))
+def _layer(w, x, *, c, kind, fp8, qblk):
+    c = dict(c)
+    pos = jnp.arange(x.shape[1])
+    return _arch(c).forward(w, x, pos, kind, c=c, fp8=fp8, qblk=qblk)
 
 
 @functools.partial(jax.jit, static_argnames=("c", "names"))
 def _draw(key, layer, *, c, names):
-    shapes = sw.canonical_shapes(dict(c))
-    dt = sw.DTYPES[dict(c)["torch_dtype"]]
-    return {n: sw.tensor(key, n, layer, shapes[n], dt).astype(jnp.float32)
-            for n in names}
+    c = dict(c)
+    arch = _arch(c)
+    shapes, tids, n_exp = arch.shapes(c), sw.ids(arch), arch.experts(c)
+    dt = sw.DTYPES[c["torch_dtype"]]
+
+    def one(n, e=None):
+        return sw.tensor(key, n, tids[n], layer, shapes[n], dt,
+                         e).astype(jnp.float32)
+
+    return {n: (jax.lax.map(lambda e, n=n: one(n, e), jnp.arange(n_exp[n]))
+                if n in n_exp else one(n)) for n in names}
+
+
+# what the shared code reads; an architecture adds its own KEYS
+KEYS = ("reference", "num_hidden_layers", "rms_norm_eps", "torch_dtype")
 
 
 def _frozen(c: dict):
-    keep = ("hidden_size", "num_attention_heads", "num_key_value_heads",
-            "head_dim", "intermediate_size", "vocab_size", "num_hidden_layers",
-            "rope_theta", "rms_norm_eps", "torch_dtype")
+    keep = set(KEYS) | set(_arch(c).KEYS)
     return tuple(sorted((k, c[k]) for k in keep if k in c))
 
 
@@ -123,12 +97,15 @@ def _hidden_pair(seed, c, tokens, *, fp8s, qblk):
     x0 = jnp.take(emb, jnp.asarray(tokens), axis=0)
     del emb
     xs = [x0 for _ in fp8s]
-    for layer in range(sw.dims(c)["layers"]):
-        w = _draw(key, layer, c=fc, names=sw.LAYER)
-        xs = [_layer(w, x, c=fc, fp8=f, qblk=qblk) for x, f in zip(xs, fp8s)]
+    arch = _arch(c)
+    for layer in range(c["num_hidden_layers"]):
+        kind = arch.kind(c, layer)
+        w = _draw(key, layer, c=fc, names=arch.tensors(c, kind))
+        xs = [_layer(w, x, c=fc, kind=kind, fp8=f, qblk=qblk)
+              for x, f in zip(xs, fp8s)]
         del w
     g = _draw(key, -1, c=fc, names=("final_norm",))["final_norm"]
-    eps = sw.dims(c)["eps"]
+    eps = float(c["rms_norm_eps"])
     return [_rms(x, g, eps) for x in xs]
 
 

@@ -1,5 +1,5 @@
-"""A small stand-in of a cell for the CPU tests: the granite-8b layout at
-the program's ``reduced()`` widths, with short traffic.  Built from the
+"""A small stand-in of a cell for the CPU tests: the configuration at its
+architecture's small sizes (``tiny``), with short traffic.  Built from the
 real files, so the tests run the real harness code on other sizes."""
 from __future__ import annotations
 
@@ -7,22 +7,13 @@ import copy
 
 import cellspec
 
-TINY = dict(hidden_size=128, intermediate_size=256, num_attention_heads=4,
-            num_key_value_heads=1, head_dim=32, num_hidden_layers=2,
-            vocab_size=512)
 PEAKS = {"bf16_flops": 1e12, "int8_ops": 2e12, "hbm_bytes_s": 1e11,
          "hbm_bytes": 1e9}
 
 
 def config(name: str = "granite-8b-l18", dtype: str = "float32") -> dict:
     c = copy.deepcopy(cellspec.config(name))
-    c.update(TINY, torch_dtype=dtype)
-    c["program"] = dict(
-        d_model=c["hidden_size"], d_ff=c["intermediate_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        vocab_size=c["vocab_size"], dtype=dtype, attn_block_kv=0,
-        remat="none")
+    c.update(cellspec.arch(c.get("reference")).tiny(c, dtype))
     return c
 
 

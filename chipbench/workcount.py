@@ -2,45 +2,47 @@
 
 Counted from the traffic (how many tokens, at what context), never from how
 a kernel is tiled, so a roofline share reads the same work whatever
-implements it.  Model FLOPs follow the usual accounting: 2 per weight per
-token for every matrix product (the embedding lookup is free), plus the
-attention products, 4 * heads * head_dim per layer per attended position.
+implements it.  Each count is the configuration's architecture's
+(``chipbench/arch/<reference>.py``, the same names and signatures), since
+which weights a token multiplies and how far it attends is the
+architecture's; model FLOPs follow the usual accounting: 2 per weight
+multiplied per token (the embedding lookup is free), plus the attention
+products over each attended position.
+
+``ctx_sum`` is the sum of the decoded tokens' attended lengths when every
+layer attends the whole context; ``spans`` lists each row's (context
+before its first decoded token, tokens decoded), from which an
+architecture whose layers attend less (a window) works out its own.
 """
 from __future__ import annotations
 
-import seedweights as sw
+import cellspec
+
+
+def _arch(c: dict):
+    return cellspec.arch(c.get("reference"))
 
 
 def matmul_params(c: dict) -> int:
-    """Weights that every token multiplies: the stack's matrices and the
-    LM head (norm gains and the embedding table excluded)."""
-    z = sw.dims(c)
-    d, h, kh, hd, ff = z["d"], z["h"], z["kh"], z["hd"], z["ff"]
-    layer = d * h * hd + 2 * d * kh * hd + h * hd * d + 3 * d * ff
-    return z["layers"] * layer + d * z["v"]
+    """Weights that a token multiplies: the stack's matrices and the LM
+    head (norm gains and the embedding table excluded)."""
+    return _arch(c).matmul_params(c)
 
 
 def weight_bytes(c: dict) -> int:
     """Bytes a decode step streams once: every served weight but the
     embedding table (of which a step reads one row per token)."""
-    z = sw.dims(c)
-    return sw.param_bytes(c) - z["v"] * z["d"] * _item(c)
-
-
-def _item(c: dict) -> int:
-    return 2 if c["torch_dtype"] == "bfloat16" else 4
+    return _arch(c).weight_bytes(c)
 
 
 def kv_bytes_per_position(c: dict) -> int:
     """K and V of one position in one layer."""
-    z = sw.dims(c)
-    return 2 * z["kh"] * z["hd"] * _item(c)
+    return _arch(c).kv_bytes_per_position(c)
 
 
 def attn_flops_per_position(c: dict) -> int:
     """QK^T and PV for one query over one attended position, one layer."""
-    z = sw.dims(c)
-    return 4 * z["h"] * z["hd"]
+    return _arch(c).attn_flops_per_position(c)
 
 
 def sum_contexts(c0: int, n: int) -> int:
@@ -49,29 +51,23 @@ def sum_contexts(c0: int, n: int) -> int:
     return n * c0 + n * (n + 1) // 2
 
 
-def decode_flops(c: dict, tokens: int, ctx_sum: int) -> int:
+def decode_flops(c: dict, tokens: int, ctx_sum: int, spans=None) -> int:
     """Model FLOPs of ``tokens`` decoded tokens whose attended lengths sum
     to ``ctx_sum``."""
-    z = sw.dims(c)
-    return (2 * matmul_params(c) * tokens
-            + attn_flops_per_position(c) * z["layers"] * ctx_sum)
+    return _arch(c).decode_flops(c, tokens, ctx_sum, spans)
 
 
-def decode_bytes(c: dict, steps: float, ctx_sum: int) -> float:
+def decode_bytes(c: dict, steps: float, ctx_sum: int, spans=None) -> float:
     """Least HBM traffic of decoding: the weights once per step, the K/V
     of every attended position once per token."""
-    z = sw.dims(c)
-    return (weight_bytes(c) * steps
-            + kv_bytes_per_position(c) * z["layers"] * ctx_sum)
+    return _arch(c).decode_bytes(c, steps, ctx_sum, spans)
 
 
-def paged_decode_bytes(c: dict, ctx_sum: int, tokens: int) -> int:
+def paged_decode_bytes(c: dict, ctx_sum: int, tokens: int, spans=None) -> int:
     """Least bytes the paged decode kernel moves, over all layers: each
     attended position's K/V once, plus each token's q read and o written."""
-    z = sw.dims(c)
-    qo = 2 * z["h"] * z["hd"] * _item(c)
-    return z["layers"] * (kv_bytes_per_position(c) * ctx_sum + qo * tokens)
+    return _arch(c).paged_decode_bytes(c, ctx_sum, tokens, spans)
 
 
-def paged_decode_flops(c: dict, ctx_sum: int) -> int:
-    return attn_flops_per_position(c) * sw.dims(c)["layers"] * ctx_sum
+def paged_decode_flops(c: dict, ctx_sum: int, spans=None) -> int:
+    return _arch(c).paged_decode_flops(c, ctx_sum, spans)
