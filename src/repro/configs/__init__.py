@@ -15,6 +15,7 @@ ARCHS: dict[str, str] = {
     "codeqwen1.5-7b": "repro.configs.codeqwen15_7b",
     "mixtral-8x22b": "repro.configs.mixtral_8x22b",
     "deepseek-moe-16b": "repro.configs.deepseek_moe_16b",
+    "mellum2-12b": "repro.configs.mellum2_12b",
     "internvl2-2b": "repro.configs.internvl2_2b",
     "whisper-small": "repro.configs.whisper_small",
     "recurrentgemma-9b": "repro.configs.recurrentgemma_9b",

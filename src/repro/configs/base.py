@@ -9,9 +9,20 @@ point (train_step vs prefill vs serve_step) they exercise.
 from __future__ import annotations
 
 import dataclasses
-from typing import Literal
+from typing import Literal, NamedTuple
 
 Family = Literal["dense", "moe", "ssm", "hybrid", "encdec", "vlm"]
+
+
+class Yarn(NamedTuple):
+    """YaRN rotary scaling (arXiv:2309.00071), the ``rope_type: yarn``
+    parameters of a Hugging Face ``rope_parameters`` entry; the frequencies
+    are :func:`repro.models.layers.yarn_inv_freq`'s."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +40,10 @@ class ModelConfig:
     # --- attention ---
     attention_window: int | None = None  # sliding-window attention (SWA)
     rope_theta: float = 10_000.0
+    # YaRN on the layers that attend without a window (the "moe_full" layers
+    # of a mixed block_pattern; every layer where no window is set); None:
+    # plain RoPE everywhere
+    rope_yarn: Yarn | None = None
     qkv_bias: bool = False
     use_rope: bool = True
     causal: bool = True
@@ -43,7 +58,8 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_d_ff: int = 0  # per-expert hidden dim (fine-grained MoE)
     capacity_factor: float = 1.25
-    moe_impl: str = "einsum"  # einsum (GShard dispatch) | sort (dropless-ish)
+    moe_impl: str = "einsum"  # einsum (GShard dispatch) | sort (capacity
+                              # buffers) | dropless (grouped matmul)
     moe_group: int = 512  # GShard dispatch group size (tokens)
     router_aux_coef: float = 0.01
 
@@ -55,8 +71,12 @@ class ModelConfig:
     ssm_chunk: int = 256
     conv_width: int = 4
 
+    # --- layer pattern: one period of the stack's layer kinds ---
+    # hybrid (griffin / RG-LRU): e.g. ("rec", "rec", "attn");
+    # moe with window and full layers: e.g. ("moe_sliding",) * 3 +
+    # ("moe_full",) -- attention_window on the sliding layers only
+    block_pattern: tuple[str, ...] = ()
     # --- hybrid (griffin / RG-LRU) ---
-    block_pattern: tuple[str, ...] = ()  # e.g. ("rec", "rec", "attn")
     lru_width: int = 0
 
     # --- enc-dec (whisper) ---
@@ -111,6 +131,8 @@ class ModelConfig:
     )
 
     def __post_init__(self):
+        if self.rope_yarn is not None and not isinstance(self.rope_yarn, Yarn):
+            object.__setattr__(self, "rope_yarn", Yarn(*self.rope_yarn))
         if self.kernel_mode not in ("auto", "pallas", "xla"):
             raise ValueError(
                 f"kernel_mode {self.kernel_mode!r}: expected auto|pallas|xla")
@@ -190,7 +212,8 @@ def shape_applicable(cfg: ModelConfig, shape: ShapeSpec) -> tuple[bool, str]:
     if shape.name == "long_500k":
         bounded = (
             cfg.family in ("ssm", "hybrid")
-            or cfg.attention_window is not None
+            or (cfg.attention_window is not None
+                and "moe_full" not in cfg.block_pattern)
         )
         if not bounded:
             return False, "pure full attention: 500k decode state unbounded/quadratic"

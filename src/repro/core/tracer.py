@@ -89,14 +89,23 @@ class Tracer:
         default is the current monotonic clock."""
         if mode is not None:
             self.pm.set_mode(mode)
-        self.t0 = _now() if t0_ns is None else int(t0_ns)
         # no JAX imported, no profiler session to anchor (and none imported
         # here: the tracer stays free of JAX)
         profiler = sys.modules.get("jax.profiler")
+        mono = None
         if profiler is not None:
-            mono = self.t0 if t0_ns is None else _now()
-            with profiler.TraceAnnotation("tracer.anchor", mono_ns=mono):
-                pass
+            # the clock is read just after the anchor starts, with nothing
+            # allocated in between: a collection of the host heap or a
+            # pause before the read would shift every span against the
+            # profiler's clock
+            note = profiler.TraceAnnotation("tracer.anchor")
+            with note:
+                mono = _now()
+                note.set_metadata(mono_ns=mono)
+        if t0_ns is not None:
+            self.t0 = int(t0_ns)
+        else:
+            self.t0 = _now() if mono is None else mono
         self._active = True
         self._open_state(ev.STATE_RUNNING)
         # anchor the base state exactly at t0 so states partition the

@@ -223,10 +223,10 @@ def multi_head_attention(
 # ----------------------------------------------------------------------
 
 
-def init_cache_spec(cfg, batch: int, max_len: int, dtype):
+def init_cache_spec(cfg, batch: int, max_len: int, dtype, window=None):
     """Abstract KV-cache entry for ONE layer (leading layer dim added by the
-    caller via stacking)."""
-    c = min(max_len, cfg.attention_window) if cfg.attention_window else max_len
+    caller via stacking); a ``window`` makes it a ring of that many slots."""
+    c = min(max_len, window) if window else max_len
     kv = (batch, c, cfg.num_kv_heads, cfg.head_dim)
     return {
         "k": jax.ShapeDtypeStruct(kv, dtype),
@@ -292,8 +292,10 @@ def attention_block(
     params, x, cfg, *, positions, cache=None, index=None,
     window=None, causal=True, use_rope=True, kv_x=None, kv_valid=None,
     cross=False, cache_len=None, block_tables=None, ring=True, row_len=None,
+    yarn=None,
 ):
-    """Returns (y, new_cache).
+    """Returns (y, new_cache).  ``yarn`` (a ``configs.base.Yarn``) switches
+    the rotary embedding to YaRN's frequencies and scale.
 
     * train/prefill: ``cache is None`` -> self-attention over x; a fresh cache
       holding the (window-truncated, ring-arranged) K/V is returned.
@@ -338,8 +340,8 @@ def attention_block(
     v = constrain(v, ("act_batch", "act_seq", "act_kv", None))
 
     if use_rope and kv_x is None:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = rope(q, positions, cfg.rope_theta, yarn)
+        k = rope(k, positions, cfg.rope_theta, yarn)
 
     if cache is None:
         if kv_x is None:

@@ -1,8 +1,11 @@
 """Shared layer primitives: norms, dense, rotary embedding, embeddings, MLP."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.params import ParamDecl
 from repro.sharding.partition import constrain, padded_vocab
@@ -95,14 +98,46 @@ def dense(params: dict, x: jax.Array, *, accum=jnp.float32) -> jax.Array:
 # ----------------------------------------------------------------------
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [..., S, H, D]; positions: [..., S] (broadcastable int32)."""
+def yarn_inv_freq(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN's rotary frequencies (arXiv:2309.00071) as Hugging Face's
+    ``_compute_yarn_parameters`` computes them: frequency i (of D/2) is
+    ``theta^(-2i/D) * ((1 - g_i) / factor + g_i)``, ``g_i = 1 - clip((i -
+    low) / (high - low), 0, 1)``, where ``low`` and ``high`` are the
+    dimensions whose wavelength makes ``beta_fast`` and ``beta_slow`` turns
+    over ``original_max_position_embeddings`` positions (floored and
+    ceiled, clamped to [0, D - 1]).  Static: float32 constants."""
+    d = head_dim
+
+    def dim_of(turns):
+        return (d * math.log(yarn.original_max_position_embeddings
+                             / (turns * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    base = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float32) / d)
+    keep = 1.0 - np.clip((np.arange(d // 2, dtype=np.float32) - low)
+                         / (high - low), 0.0, 1.0)
+    return (base / yarn.factor * (1.0 - keep) + base * keep).astype(np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         yarn=None) -> jax.Array:
+    """x: [..., S, H, D]; positions: [..., S] (broadcastable int32).
+    ``yarn`` (a ``configs.base.Yarn``): YaRN's frequencies, and cos and sin
+    scaled by its ``attention_factor``."""
     d = x.shape[-1]
     half = d // 2
-    freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if yarn is None:
+        freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    else:
+        freq = jnp.asarray(yarn_inv_freq(d, theta, yarn))
     ang = positions[..., :, None].astype(jnp.float32) * freq  # [..., S, half]
     cos = jnp.cos(ang)[..., :, None, :]  # [..., S, 1, half]
     sin = jnp.sin(ang)[..., :, None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = x[..., :half], x[..., half:]
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin

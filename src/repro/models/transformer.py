@@ -7,7 +7,11 @@ as 2 layers).  A unit is:
   * dense / moe / ssm families: one layer;
   * hybrid (griffin): one super-block following ``cfg.block_pattern``
     (e.g. ("rec","rec","attn")); layers that don't fill a whole super-block
-    form a separately-scanned "tail" (recurrentgemma-9b: 12x(r,r,a) + 2r).
+    form a separately-scanned "tail" (recurrentgemma-9b: 12x(r,r,a) + 2r);
+  * moe with a ``block_pattern`` of sliding and full layers (mellum2-12b:
+    3 x "moe_sliding" + "moe_full"): one period, each sub-layer with its
+    own window and rotary embedding (:func:`layer_window`,
+    :func:`layer_yarn`) and its own stacked cache.
 
 Three modes share one code path: "train" (no caches), "prefill" (caches
 collected as scan outputs) and "decode" (caches threaded through the scan).
@@ -34,16 +38,33 @@ from repro.sharding.partition import constrain
 # ----------------------------------------------------------------------
 
 
+# an attention + MoE layer: windowed as configured ("moe"), or in a mixed
+# pattern, windowed ("moe_sliding") or attending everything ("moe_full")
+MOE_KINDS = ("moe", "moe_sliding", "moe_full")
+
+
 def unit_kinds(cfg) -> tuple[str, ...]:
     if cfg.family in ("dense", "vlm"):
         return ("dense",)
     if cfg.family == "moe":
-        return ("moe",)
+        return tuple(cfg.block_pattern) or ("moe",)
     if cfg.family == "ssm":
         return ("ssm",)
     if cfg.family == "hybrid":
         return tuple(cfg.block_pattern)
     raise ValueError(cfg.family)
+
+
+def layer_window(cfg, kind: str) -> int | None:
+    """A layer's attention window: ``cfg.attention_window`` on every
+    attention layer but a mixed pattern's full layers."""
+    return None if kind == "moe_full" else cfg.attention_window
+
+
+def layer_yarn(cfg, kind: str):
+    """A layer's YaRN parameters: ``cfg.rope_yarn`` on the layers that
+    attend without a window, plain RoPE (None) on the others."""
+    return cfg.rope_yarn if layer_window(cfg, kind) is None else None
 
 
 def scan_counts(cfg) -> tuple[int, int]:
@@ -62,7 +83,7 @@ def layer_decl(cfg, kind: str) -> dict:
     elif kind in ("dense", "attn"):
         decl["attn"] = attn_mod.attn_decl(cfg)
         decl["mlp"] = mlp_decl(cfg)
-    elif kind == "moe":
+    elif kind in MOE_KINDS:
         decl["attn"] = attn_mod.attn_decl(cfg)
         decl["moe"] = moe_mod.moe_decl(cfg)
     else:
@@ -100,7 +121,8 @@ def layer_cache_spec(cfg, kind: str, batch: int, max_len: int, dtype):
         return ssm_mod.mamba2_state_spec(cfg, batch, dtype)
     if kind == "rec":
         return rglru_mod.griffin_rec_state_spec(cfg, batch, dtype)
-    return attn_mod.init_cache_spec(cfg, batch, max_len, dtype)
+    return attn_mod.init_cache_spec(cfg, batch, max_len, dtype,
+                                    layer_window(cfg, kind))
 
 
 def layer_cache_axes(kind: str):
@@ -241,18 +263,18 @@ def apply_layer(params, x, cfg, kind, *, positions, cache, index, cache_len=None
     if kind == "rec":
         y, new_cache = rglru_mod.griffin_rec_block(params["rec"], h, cfg, state=cache)
     else:
-        window = cfg.attention_window
         y, new_cache = attn_mod.attention_block(
             params["attn"], h, cfg, positions=positions, cache=cache,
-            index=index, window=window, causal=cfg.causal, use_rope=cfg.use_rope,
-            cache_len=cache_len, block_tables=block_tables, ring=ring,
-            row_len=row_len,
+            index=index, window=layer_window(cfg, kind), causal=cfg.causal,
+            use_rope=cfg.use_rope, cache_len=cache_len,
+            block_tables=block_tables, ring=ring, row_len=row_len,
+            yarn=layer_yarn(cfg, kind),
         )
     x = x + y
     x = constrain(x, ("act_batch", "act_seq_resid", "act_embed"))
 
     h = apply_norm(params["ln2"], x, cfg.norm_eps)
-    if kind == "moe":
+    if kind in MOE_KINDS:
         y, aux = moe_mod.moe_block(params["moe"], h, cfg)
     else:
         y = apply_mlp(params["mlp"], h, cfg)

@@ -521,7 +521,8 @@ class UnifiedServeEngine(ContinuousServeEngine):
         self._whole_tokens = 0
         with span("serve.unified_step", tr, steps=steps,
                   decode_rows=len(pairs), chunk_tokens=n_chunk,
-                  kv_pages=self._decode_pages(pairs, steps)):
+                  kv_pages=self._decode_pages(pairs, steps),
+                  kv_pages_window=self._window_pages(pairs, steps)):
             key, ck_tokens, ck_start, ck_len, ck_slot, ck_sample = \
                 self._prep_dispatch(chunks)
             t_dispatch = _now_ns()
@@ -559,25 +560,38 @@ class UnifiedServeEngine(ContinuousServeEngine):
                 tr.emit(ev.EV_DECODE_TOKENS, len(pairs))
             return toks, ck_fan, pairs, chunks, t_dispatch, coll_ops
 
-    def _decode_pages(self, pairs, steps: int) -> int:
+    def _window_pages(self, pairs, steps: int) -> int:
+        """:meth:`_decode_pages` in one sliding-window layer; 0 where no
+        layer has a window."""
+        window = self.cfg.attention_window
+        return self._decode_pages(pairs, steps, window) if window else 0
+
+    def _decode_pages(self, pairs, steps: int, window: int | None = None) -> int:
         """Table entries the paged decode kernel reads for this dispatch's
-        decode rows, summed over its ``steps`` iterations, in one
-        full-attention layer: a row at position p reads entries 0 ..
-        min(p // bs, W - 1) (windowed layers skip the stale ones)."""
+        decode rows, summed over its ``steps`` iterations, in one layer: a
+        row at position p reads entries lo .. min(p // bs, W - 1), where lo
+        is 0 in a full-attention layer and, in a layer of ``window``
+        positions, the entry of position p - window + 1 (the kernel's
+        ``_live_pages``)."""
         if not self._has_paged:
             return 0
         bs, w = self.block_size, self._tables.shape[1]
 
-        def upto(n):  # entries read by positions 0 .. n - 1, summed
+        def upto(n):  # entries min(q // bs, W - 1) + 1 for q < n, summed
+            n = max(n, 0)
             k, r = divmod(min(n, w * bs), bs)
             return bs * k * (k + 1) // 2 + r * (k + 1) + max(n - w * bs, 0) * w
+
+        def first(n):  # entries lo for q < n, summed (each q - window + 1)
+            m = max(n - window + 1, 0) if window else 0
+            return upto(m) - m
 
         pages = 0
         for slot, req in pairs:
             # the row's first position this dispatch (as _ensure_blocks)
             p = (int(self._slot_start[slot]) + req.scheduled
                  - int(self._slot_sched0[slot]) - 1)
-            pages += upto(p + steps) - upto(max(p, 0))
+            pages += upto(p + steps) - upto(p) - (first(p + steps) - first(p))
         return pages
 
     def _advance_chunks(self, chunks: list[ChunkPlan], t_dispatch,

@@ -64,3 +64,42 @@ def test_shared_experts_always_active():
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 8, cfg.d_model), jnp.float32)
     y, _ = moe_block(params, x, cfg)
     assert float(jnp.abs(y).mean()) > 0  # shared experts still contribute
+
+
+def _dropless(k=1, seed=0):
+    cfg = reduced(get_config("mellum2-12b"))
+    assert cfg.moe_impl == "dropless"
+    cfg = cfg.replace(experts_per_token=k)
+    params = init_params(jax.random.PRNGKey(seed), moe_decl(cfg), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (2, 16, cfg.d_model),
+                          jnp.float32)
+    return cfg, params, x
+
+
+def test_dropless_is_exact_when_every_token_routes_to_one_expert():
+    """A zero router ties every expert; top-1 then sends all 32 tokens to
+    expert 0 with gate 1.  The dropless path gives each of them that
+    expert's SwiGLU (float32, to rounding); the capacity path at the
+    published capacity factor 1.25 keeps 32 * 1/8 * 1.25 = 5 of them."""
+    cfg, params, x = _dropless(k=1)
+    params = dict(params, router={"w": jnp.zeros_like(params["router"]["w"])})
+    y, _ = moe_block(params, x, cfg)
+    ex = {n: w[0] for n, w in params["experts"].items()}
+    want = (jax.nn.silu(x @ ex["w_gate"]) * (x @ ex["w_up"])) @ ex["w_down"]
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+    y_cap, _ = moe_block(params, x, cfg.replace(moe_impl="sort",
+                                                capacity_factor=1.25))
+    kept = np.abs(np.asarray(y_cap)).sum(-1) > 0
+    assert kept.sum() == 5
+
+
+def test_dropless_matches_the_capacity_paths_when_nothing_drops():
+    cfg, params, x = _dropless(k=2)
+    y, aux = jax.jit(lambda p, v: moe_block(p, v, cfg))(params, x)
+    for impl in ("einsum", "sort"):
+        other = cfg.replace(moe_impl=impl, capacity_factor=8.0)
+        y_o, aux_o = jax.jit(lambda p, v: moe_block(p, v, other))(params, x)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_o), rtol=2e-4,
+                                   atol=2e-4)
+        np.testing.assert_allclose(float(aux), float(aux_o), rtol=1e-5)

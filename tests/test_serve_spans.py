@@ -44,24 +44,22 @@ def _host_spans(path):
     return sorted(out, key=lambda s: s[2])
 
 
-@pytest.fixture(scope="module", params=["unified", "spec"])
-def profiled(request, tmp_path_factory):
-    cfg = reduced(get_config("granite-8b"), num_layers=2)
+def _serve_profiled(tmp, cfg, *, block_size=8, new_tokens=6, **extra):
+    """A tiny engine serving three prompts under a profiler session:
+    (spans, tracer, trace, engine), ``engine.dispatched`` holding each
+    dispatch's decode rows, steps and the slots' device positions."""
     params = build_model(cfg).init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
                for n in (11, 19, 6)]
-    tmp = tmp_path_factory.mktemp(f"prof-{request.param}")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp), profiler_options=opts)
     try:
         tracer = Tracer("spans").init()
-        extra = ({"spec": NGramProposer(), "spec_k": 3}
-                 if request.param == "spec" else {})
         eng = UnifiedServeEngine(cfg, params, num_slots=2, max_len=64,
-                                 block_size=8, chunk_size=8, tracer=tracer,
-                                 **extra)
+                                 block_size=block_size, chunk_size=8,
+                                 tracer=tracer, **extra)
         # what each decode dispatch's kernel will read: its rows and the
         # slots' position registers on the device as it is dispatched
         seen = []
@@ -74,14 +72,23 @@ def profiled(request, tmp_path_factory):
             return dispatch(pairs, steps, chunks)
         eng._dispatch = recording
         for p in prompts:
-            eng.submit(p, 6)
+            eng.submit(p, new_tokens)
         eng.run()
         trace = tracer.finish()
     finally:
         jax.profiler.stop_trace()
     path = glob.glob(f"{tmp}/**/*.xplane.pb", recursive=True)[0]
     eng.dispatched = seen
-    return request.param, _host_spans(path), tracer, trace, eng
+    return _host_spans(path), tracer, trace, eng
+
+
+@pytest.fixture(scope="module", params=["unified", "spec"])
+def profiled(request, tmp_path_factory):
+    cfg = reduced(get_config("granite-8b"), num_layers=2)
+    extra = ({"spec": NGramProposer(), "spec_k": 3}
+             if request.param == "spec" else {})
+    return (request.param, *_serve_profiled(
+        tmp_path_factory.mktemp(f"prof-{request.param}"), cfg, **extra))
 
 
 def test_each_dispatch_runs_plan_blocks_dispatch_fetch_retire(profiled):
@@ -129,6 +136,36 @@ def test_unified_step_counts_the_decode_kernels_live_pages(profiled):
             for rows, steps, idx in eng.dispatched]
     assert [s["kv_pages"] for s in stats] == want
     assert any(want)
+
+
+def _window_entries(q, bs, w, window):
+    """Table entries the decode kernel walks for a row at position q in a
+    layer of ``window`` positions (paged.py ``_live_pages``)."""
+    hi = min(q // bs, w - 1)
+    return hi - min(max(q - window + 1, 0) // bs, hi) + 1
+
+
+@pytest.mark.parametrize("window", [None, 12])
+def test_unified_step_counts_the_window_layers_live_pages(window,
+                                                         tmp_path):
+    """``kv_pages_window``: the entries the decode kernel walks in one
+    sliding-window layer, summed over the dispatch's rows and steps; 0
+    where no layer has a window.  Blocks of 4 positions and 20 decoded
+    tokens put rows past the window, where the first entries drop out."""
+    cfg = reduced(get_config("granite-8b"), num_layers=2,
+                  attention_window=window)
+    spans, _, _, eng = _serve_profiled(tmp_path, cfg, block_size=4,
+                                       new_tokens=20)
+    stats = [s[4] for s in spans if s[1] == "serve.unified_step"]
+    assert len(stats) == len(eng.dispatched)
+    bs, w = eng.block_size, eng._tables.shape[1]
+    want = [sum(_window_entries(int(idx[s]) + t, bs, w, window)
+                for s in rows for t in range(steps)) if window else 0
+            for rows, steps, idx in eng.dispatched]
+    assert [s["kv_pages_window"] for s in stats] == want
+    if window:
+        full = [s["kv_pages"] for s in stats]
+        assert any(want) and sum(want) < sum(full)
 
 
 def test_prv_regions_align_with_profiler_spans(profiled):

@@ -302,3 +302,34 @@ def test_max_new_tokens_one_completes_at_chunk():
     out_ref = ref.run()
     for a, b in zip(reqs, rr):
         np.testing.assert_array_equal(out[a.rid], out_ref[b.rid])
+
+
+def test_mellum_serve_matches_greedy_forward_past_the_window():
+    """mellum2-12b's pattern (three windowed layers, then a full YaRN layer;
+    dropless experts) through chunked prefill across the window, then
+    paged decode past it: every served token is the argmax of the
+    teacher-forced forward over the prompt and the tokens served before
+    it.  The forward with the window on every layer, or with plain RoPE,
+    picks other tokens on the same sequences."""
+    cfg = reduced(get_config("mellum2-12b"), attention_window=8,
+                  vocab_size=128)
+    params = build_model(cfg).init(jax.random.PRNGKey(3))
+    prompts = _prompts(cfg, [21, 30], seed=5)
+    uni = UnifiedServeEngine(cfg, params, num_slots=2, max_len=64,
+                             block_size=16, chunk_size=8)
+    reqs = [uni.submit(p, 12) for p in prompts]
+    out = uni.run()
+    seqs = [np.concatenate([p, out[r.rid]]).astype(np.int32)
+            for p, r in zip(prompts, reqs)]
+
+    def agree(c):
+        fwd = jax.jit(lambda p, t: build_model(c).forward(
+            p, {"tokens": t}, mode="train")[0])
+        return [np.array_equal(
+            np.asarray(fwd(params, s[None, :-1]))[0, len(p) - 1:].argmax(-1),
+            out[r.rid]) for s, p, r in zip(seqs, prompts, reqs)]
+
+    assert agree(cfg) == [True, True]
+    for other in (cfg.replace(block_pattern=("moe_sliding",) * 4),
+                  cfg.replace(rope_yarn=None)):
+        assert not all(agree(other)), other.block_pattern
